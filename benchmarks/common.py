@@ -16,9 +16,13 @@ import numpy as np
 from repro.core import pack as P
 from repro.core import quant as Q
 
-# v5e projection constants (same as roofline)
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
+from repro.devices import V5E_KIND, peaks
+
+# v5e projection constants (the table roofline.py reads too)
+_V5E = peaks(V5E_KIND)
+PEAK_FLOPS = _V5E["bf16_flops"]
+INT8_OPS = _V5E["int8_ops"]
+HBM_BW = _V5E["hbm_bw"]
 # energy proxy constants (order-of-magnitude DRAM/MAC energies, documented)
 PJ_PER_HBM_BYTE = 15.0
 PJ_PER_MAC_INT8 = 0.2

@@ -13,7 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from benchmarks.common import (
-    HBM_BW, PEAK_FLOPS, csv_row, ref_layer_bytes, ref_layer_macs,
+    HBM_BW, INT8_OPS, PEAK_FLOPS, csv_row, ref_layer_bytes, ref_layer_macs,
     ref_layer_tensors, timeit,
 )
 from repro.core import pack as P
@@ -48,7 +48,7 @@ def run():
         b_q = sum(ref_layer_bytes(x_bits, w_bits, y_bits).values())
         b_fp = sum(ref_layer_bytes(32, 32, 32).values())
         # v5e: this layer is tiny -> memory-bound; projected speedup = bytes ratio
-        t_q = max(b_q / HBM_BW, 2 * macs / PEAK_FLOPS)
+        t_q = max(b_q / HBM_BW, 2 * macs / INT8_OPS)  # int8 MXU
         t_fp = max(b_fp / HBM_BW, 2 * macs / (PEAK_FLOPS / 2))  # fp32: half MXU rate
         csv_row(
             f"fig5_speedup_u{x_bits}_i{w_bits}_u{y_bits}", us_q,

@@ -24,6 +24,9 @@ def main() -> None:
     only = set(args.only.split(",")) if args.only else None
     if args.json_dir:
         os.environ["BENCH_OUT_DIR"] = args.json_dir
+    from repro import compile_cache
+
+    compile_cache.enable()
 
     from benchmarks import (fig4_matmul, fig5_speedup, fig6_energy, load_gen,
                             lm_serving, tab1_qntpack)

@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 from repro.core import pack as P
 from repro.kernels.mpmm import _requant_block, _unpack_x
 
@@ -64,7 +62,7 @@ def _conv2d_kernel(
     if x_off:
         wsum = jnp.sum(w.astype(jnp.int32), axis=1)  # (Cout,)
         phi = phi + x_off * wsum[None, :]
-    y = _requant_block(phi, rqv_ref, y_bits)  # (bh*W, Cout) uint8
+    y = _requant_block(phi, rqv_ref, y_bits)  # (bh*W, Cout) int32
     o_ref[...] = P.pack(y, y_bits).reshape(bh, W, -1)
 
 
@@ -77,7 +75,7 @@ def conv2d_pallas(
     w_bits: int,
     y_bits: int,
     bh: int = 1,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     Hp, Wp, Cp = x_pad_p.shape
     H, W = Hp - 2, Wp - 2
@@ -99,7 +97,7 @@ def conv2d_pallas(
         ],
         out_specs=pl.BlockSpec((bh, W, Cout // ry), lambda h: (h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((H, W, Cout // ry), jnp.int8),
-        compiler_params=compat.CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name=f"conv3x3_u{x_bits}_i{w_bits}_u{y_bits}",
     )(x_pad_p, w_p, rqv)

@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 BIG_NEG = -2.0e9
 
 
@@ -85,7 +83,7 @@ def flash_mha_pallas(
     window: int | None = None,
     bq: int = 512,
     bk: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Returns (B, Hq, Sq, D). Sq/Sk padded to block multiples internally."""
     B, Hq, Sq, D = q.shape
@@ -122,7 +120,7 @@ def flash_mha_pallas(
             pltpu.VMEM((bq_,), jnp.float32),  # running denom
             pltpu.VMEM((bq_, D), jnp.float32),  # accumulator
         ],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name=f"flash_{'causal' if causal else 'full'}"

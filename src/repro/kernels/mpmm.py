@@ -35,10 +35,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 from repro.core import pack as P
 from repro.core import quant as Q
+from repro.kernels.tuning import IllegalTile
 
 
 def _unpack_x(block: jax.Array, x_bits: int, x_signed: bool = False) -> jax.Array:
@@ -51,17 +50,16 @@ def _unpack_x(block: jax.Array, x_bits: int, x_signed: bool = False) -> jax.Arra
     offset-binary (q + 2^(b-1)); true value = u - 2^(b-1), which is exactly
     what the subtraction yields -> no compensation (comp_offset = 0).
     """
-    u = P.unpack(block, x_bits, signed=False)  # raw unsigned field values
+    u = P.fields(block, x_bits, signed=False)  # raw unsigned field values
     off = (1 << (x_bits - 1)) if (x_signed or x_bits == 8) else 0
     if off:
-        xs = (u.astype(jnp.int32) - off).astype(jnp.int8)
-        return xs, (0 if x_signed else off)
+        return (u - off).astype(jnp.int8), (0 if x_signed else off)
     return u.astype(jnp.int8), 0  # 0..15 / 0..3 fit s8 directly
 
 
 def _requant_block(acc: jax.Array, rqv_ref, y_bits: int) -> jax.Array:
-    """Fused QntPack on an int32 accumulator block. rqv layout:
-    [0]=shift, [1]=bias, [2:2+2^y-1]=thresholds."""
+    """Fused QntPack on an int32 accumulator block: int32 values in
+    [0, 2^y_bits). rqv layout: [0]=shift, [1]=bias, [2:2+2^y-1]=thresholds."""
     if y_bits == 8:
         shift = rqv_ref[0]
         bias = rqv_ref[1]
@@ -72,7 +70,7 @@ def _requant_block(acc: jax.Array, rqv_ref, y_bits: int) -> jax.Array:
         y = jnp.zeros(acc.shape, jnp.int32)
         for i in range(n_thresh):  # 3 (2-bit) or 15 (4-bit) VPU compares
             y = y + (acc >= rqv_ref[2 + i]).astype(jnp.int32)
-    return y.astype(jnp.uint8)
+    return y
 
 
 def _mpmm_kernel(
@@ -115,7 +113,7 @@ def _mpmm_kernel(
         elif out_kind == "int32":
             o_ref[...] = acc
         else:
-            y = _requant_block(acc, rqv_ref, y_bits)  # (bm, bn) uint8
+            y = _requant_block(acc, rqv_ref, y_bits)  # (bm, bn) int32
             o_ref[...] = P.pack(y, y_bits)  # (bm, bn/ry) int8
 
 
@@ -133,18 +131,22 @@ def mpmm_pallas(
     bm: int = 256,
     bn: int = 256,
     bk: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
-    """Blocked mixed-precision matmul. Shapes must divide the block sizes
-    (ops.py pads). Returns packed (M, N/ry) int8, or (M, N) f32/int32."""
+    """Blocked mixed-precision matmul. ``bn``/``bk`` snap to whole layout
+    groups (``pack.snap_block``); shapes must then divide the blocks (ops.py
+    pads). Returns packed (M, N/ry) int8, or (M, N) f32/int32."""
     rx, rw, ry = P.pack_ratio(x_bits), P.pack_ratio(w_bits), P.pack_ratio(y_bits)
     M, Kx = x_p.shape
     N, Kw = w_p.shape
     K = Kx * rx
-    assert Kw * rw == K, f"K mismatch: x gives {K}, w gives {Kw * rw}"
-    bm, bn, bk = min(bm, M), min(bn, N), min(bk, K)
-    assert M % bm == 0 and N % bn == 0 and K % bk == 0, (M, N, K, bm, bn, bk)
-    assert bk % rx == 0 and bk % rw == 0 and bn % ry == 0
+    if Kw * rw != K:
+        raise ValueError(f"K mismatch: x gives {K}, w gives {Kw * rw}")
+    bm = min(bm, M)
+    bn = P.snap_block(bn, N, ry if out_kind == "packed" else 1)
+    bk = P.snap_block(bk, K, max(rx, rw))
+    if M % bm or N % bn or K % bk:
+        raise IllegalTile(f"blocks {(bm, bn, bk)} do not divide {(M, N, K)}")
     k_steps = K // bk
 
     if out_kind == "packed":
@@ -180,7 +182,7 @@ def mpmm_pallas(
         out_specs=out_spec,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -81,8 +81,8 @@ def mpmm(
         overrides={"bm": bm, "bn": bn, "bk": bk},
     )
     bm_ = min(t["bm"], _ceil(M, 8))
-    bn_ = min(t["bn"], _ceil(N, 128))
-    bk_ = min(t["bk"], _ceil(K, 128))
+    bn_ = P.snap_block(t["bn"], N, ry if out_kind == "packed" else 1)
+    bk_ = P.snap_block(t["bk"], K, max(rx, rw))
     xp = _pad_axis(_pad_axis(x_p, 0, bm_), 1, bk_ // rx)
     wp = _pad_axis(_pad_axis(w_p, 0, bn_), 1, bk_ // rw)
     rqv = requant_vector(rq)
@@ -113,7 +113,9 @@ def qntpack(
         "qntpack", perm=tuning.perm_key(y_bits=y_bits),
         shape=tuning.shape_key(M, N), overrides={"bm": bm},
     )
-    bm_ = min(t["bm"], _ceil(M, 8))
+    # a block holds bm whole int32 rows, double-buffered in VMEM: keep the
+    # pair within 4 MiB (a wide row, d_ff = 8192, takes bm <= 64)
+    bm_ = min(t["bm"], _ceil(M, 8), max(8, (1 << 22) // (8 * N) // 8 * 8))
     ry = P.pack_ratio(y_bits)
     phip = _pad_axis(phi, 0, bm_)
     y = entry.fn(phip, requant_vector(rq), bm=bm_, interpret=_interpret())
@@ -179,13 +181,25 @@ def wdqmm(
         overrides={"bm": bm, "bn": bn, "bk": bk},
     )
     bm_ = min(t["bm"], _ceil(M, 8))
-    bn_ = min(t["bn"], _ceil(N, 128))
-    bk_ = min(t["bk"], _ceil(K, 128))
+    bn_ = P.snap_block(t["bn"], N, 1)
+    bk_ = P.snap_block(t["bk"], K, rw)
     xp = _pad_axis(_pad_axis(x, 0, bm_), 1, bk_)
     wp = _pad_axis(_pad_axis(w_p, 0, bn_), 1, bk_ // rw)
     y = entry.fn(xp, wp, jnp.asarray(eps_w, jnp.float32).reshape(1),
                  bm=bm_, bn=bn_, bk=bk_, interpret=_interpret())
     return y[:M, :N]
+
+
+def flash_mha(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool,
+              window: Optional[int], bq: int, bk: int) -> jax.Array:
+    """Pallas flash attention forward over (B, H, S, D) operands
+    (kernels/flash.py); not a registry cell — models/attention.py calls it
+    when its ``impl`` resolves to Pallas and runs its own jnp path
+    otherwise."""
+    from repro.kernels.flash import flash_mha_pallas
+
+    return flash_mha_pallas(q, k, v, causal=causal, window=window, bq=bq,
+                            bk=bk, interpret=_interpret())
 
 
 def paged_gather(

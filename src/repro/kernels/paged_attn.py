@@ -15,9 +15,11 @@ Two variants cover the model zoo's decode shapes:
 
   * ``paged_attn``      — GQA decode: one query token per slot attends over
     K/V pages ``(n_pages, page_size, Hkv, D/r)`` + per-(token, head) scale
-    pages. Grid ``(B, Hkv, n_blocks)``; each step scores the kv head's
-    ``groups`` query heads against one page, so a page is read once per kv
-    head (not once per q head). Sliding-window masking (SWA archs) is fused.
+    pages. Grid ``(B, n_blocks)``; each step DMAs one whole page (every kv
+    head: a block whose last two dims are the pool's own, as Mosaic requires)
+    and scores each kv head's ``groups`` query heads against that head's
+    rows, so a page is read once per slot. Sliding-window masking (SWA
+    archs) is fused.
   * ``paged_mla_attn``  — MLA absorbed decode: latent-KV pages stay
     COMPRESSED in the pool (kv_lora-wide ``c`` rows + shared rope key ``r``
     rows; SNIPPETS.md Snippet 3's matrix absorption). The kernel scores
@@ -39,7 +41,7 @@ block table — ops.paged_attn reshapes ``(B, S_max, ...)`` stripes into a
 prefix backends all share this code path; with equal block/page sizes their
 outputs are bit-identical (gather and dequantize commute elementwise).
 
-Both variants ship the usual pair: the Pallas kernel (interpret=True off-TPU)
+Both variants ship the usual pair: the Pallas kernel (interpreted off-TPU)
 and a jnp twin mirroring the page-blocked reduction step for step (same dots,
 same masks, same flush — agreement is ulp-level, bounded only by XLA's
 reassociation freedom; the integer-matmul twins elsewhere in this package are
@@ -60,7 +62,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import pack as P
-from repro.kernels import compat
 
 BIG_NEG = -2.0e9
 
@@ -68,12 +69,13 @@ BIG_NEG = -2.0e9
 def _dequant_block(qv: jax.Array, scale: Optional[jax.Array],
                    bits: Optional[int]) -> jax.Array:
     """(ps, D/r) stored block -> (ps, D) f32, matching kv_dequantize bit-for-
-    bit: int8/int4 rows scale then round through bf16; bf16 rows just widen."""
+    bit: int8/int4 rows scale (``scale`` is the (ps, 1) column) then round
+    through bf16; bf16 rows just widen."""
     if bits is None:
         return qv.astype(jnp.float32)
     if bits < 8:
         qv = P.unpack(qv, bits, signed=True)
-    x = qv.astype(jnp.float32) * scale[:, None]
+    x = qv.astype(jnp.float32) * scale
     return x.astype(jnp.bfloat16).astype(jnp.float32)
 
 
@@ -92,51 +94,81 @@ def _bdot(a: jax.Array, b: jax.Array, *, trans: bool = False) -> jax.Array:
     return fn(a, b)
 
 
+def _page_spec(leaf: Optional[jax.Array]) -> pl.BlockSpec:
+    """One whole pool page per grid step, routed through the prefetched block
+    table. A page block keeps the pool's trailing dims whole, which is what
+    Mosaic requires of the last two block dims; a dummy (1,) spec stands in
+    for the scales of a bf16 cache."""
+    if leaf is None:
+        return pl.BlockSpec((1,), lambda b, j, bt, pos: (0,))
+    tail = leaf.shape[1:]
+    return pl.BlockSpec((1, *tail),
+                        lambda b, j, bt, pos: (bt[b, j],) + (0,) * len(tail))
+
+
+def _softmax_update(m_ref, l_ref, acc_ref, rows, s, valid, v):
+    """One page's running-softmax update of query rows ``rows``: scores
+    ``s`` (G, ps), mask ``valid`` (1, ps), values ``v`` (ps, Dv)."""
+    s = jnp.where(valid, s, BIG_NEG)
+    m_prev = m_ref[rows]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    # p is forced to exactly 0.0 under the mask: a fully-masked page (window
+    # slid past it, or a recycled page beyond the write frontier) leaves
+    # m == BIG_NEG, where exp(s - m) would be exp(0) = 1, not 0
+    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+    alpha = jnp.exp(m_prev - m_new)
+    l_ref[rows] = l_ref[rows] * alpha + jnp.sum(p, axis=1, keepdims=True)
+    acc_ref[rows] = acc_ref[rows] * alpha + jax.lax.dot_general(
+        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    m_ref[rows] = m_new
+
+
+def _init_state(m_ref, l_ref, acc_ref):
+    m_ref[...] = jnp.full_like(m_ref, BIG_NEG)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+
+def _flush(o_ref, l_ref, acc_ref):
+    o_ref[0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+
+
 # ------------------------------------------------------------- GQA decode
 
 
 def _paged_attn_kernel(bt_ref, pos_ref, q_ref, kq_ref, ks_ref, vq_ref, vs_ref,
                        o_ref, m_ref, l_ref, acc_ref, *,
-                       bits: Optional[int], ps: int, nb: int, scale: float,
-                       window: Optional[int]):
+                       bits: Optional[int], ps: int, nb: int, groups: int,
+                       scale: float, window: Optional[int]):
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, BIG_NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _init_state(m_ref, l_ref, acc_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32)  # (G, D)
-    k = _dequant_block(kq_ref[0, :, 0],
-                       None if bits is None else ks_ref[0, :, 0], bits)
-    v = _dequant_block(vq_ref[0, :, 0],
-                       None if bits is None else vs_ref[0, :, 0], bits)
-
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
     kpos = j * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
     valid = kpos <= pos_ref[b]
     if window is not None:
         valid &= (pos_ref[b] - kpos) < window
-    s = jnp.where(valid, s, BIG_NEG)
-
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-    # p is forced to exactly 0.0 under the mask: a fully-masked page (window
-    # slid past it, or a recycled page beyond the write frontier) leaves
-    # m == BIG_NEG, where exp(s - m) would be exp(0) = 1, not 0
-    p = jnp.where(valid, jnp.exp(s - m_new[:, None]), 0.0)
-    alpha = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
+    ks = vs = None
+    if bits is not None:
+        ks, vs = ks_ref[0], vs_ref[0]  # (ps, Hkv)
+    for h in range(kq_ref.shape[2]):
+        rows = pl.ds(h * groups, groups)  # q head kv*G + g (jnp.repeat order)
+        col = slice(h, h + 1)
+        k = _dequant_block(kq_ref[0, :, h, :],
+                           None if ks is None else ks[:, col], bits)
+        v = _dequant_block(vq_ref[0, :, h, :],
+                           None if vs is None else vs[:, col], bits)
+        q = q_ref[0, rows, :].astype(jnp.float32)  # (G, D)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        _softmax_update(m_ref, l_ref, acc_ref, rows, s, valid, v)
 
     @pl.when(j == nb - 1)
-    def _flush():
-        o_ref[0, 0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[:, None]
+    def _done():
+        _flush(o_ref, l_ref, acc_ref)
 
 
 def paged_attn_pallas(
@@ -150,55 +182,47 @@ def paged_attn_pallas(
     *,
     bits: Optional[int],
     window: Optional[int] = None,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Returns (B, Hq, D) f32. Scalar-prefetched block table + per-slot pos;
-    one grid step = one (slot, kv head, page) running-softmax update."""
+    one grid step = one (slot, page) running-softmax update over every kv
+    head — each head's ``groups`` query rows score the page's rows of that
+    head, so a page is read once per slot."""
     B, Hq, D = q.shape
-    P_, ps, Hkv, _ = k.shape
-    G = Hq // Hkv
+    Hkv = k.shape[2]
+    ps = k.shape[1]
     NB = block_table.shape[1]
-    scale = 1.0 / (D**0.5)
-    q4 = q.reshape(B, Hkv, G, D)  # q head h = kv*G + g (jnp.repeat order)
     pos = jnp.asarray(pos, jnp.int32).reshape(B)
-
-    kernel = functools.partial(_paged_attn_kernel, bits=bits, ps=ps, nb=NB,
-                               scale=scale, window=window)
     quant = bits is not None
-    in_specs = [
-        pl.BlockSpec((1, 1, G, D), lambda b, h, j, bt, pos: (b, h, 0, 0)),
-        pl.BlockSpec((1, ps, 1, k.shape[-1]),
-                     lambda b, h, j, bt, pos: (bt[b, j], 0, h, 0)),
-        pl.BlockSpec((1, ps, 1), lambda b, h, j, bt, pos: (bt[b, j], 0, h))
-        if quant else pl.BlockSpec((1,), lambda b, h, j, bt, pos: (0,)),
-        pl.BlockSpec((1, ps, 1, v.shape[-1]),
-                     lambda b, h, j, bt, pos: (bt[b, j], 0, h, 0)),
-        pl.BlockSpec((1, ps, 1), lambda b, h, j, bt, pos: (bt[b, j], 0, h))
-        if quant else pl.BlockSpec((1,), lambda b, h, j, bt, pos: (0,)),
-    ]
+    kernel = functools.partial(
+        _paged_attn_kernel, bits=bits, ps=ps, nb=NB, groups=Hq // Hkv,
+        scale=1.0 / (D**0.5), window=window)
     zero = jnp.zeros((1,), jnp.float32)  # dummy scale operand when bf16
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, Hkv, NB),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, j, bt, pos: (b, h, 0, 0)),
+        grid=(B, NB),
+        in_specs=[
+            pl.BlockSpec((1, Hq, D), lambda b, j, bt, pos: (b, 0, 0)),
+            _page_spec(k), _page_spec(k_s if quant else None),
+            _page_spec(v), _page_spec(v_s if quant else None),
+        ],
+        out_specs=pl.BlockSpec((1, Hq, D), lambda b, j, bt, pos: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G, D), jnp.float32),
+            pltpu.VMEM((Hq, 1), jnp.float32),
+            pltpu.VMEM((Hq, 1), jnp.float32),
+            pltpu.VMEM((Hq, D), jnp.float32),
         ],
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), jnp.float32),
-        compiler_params=compat.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        out_shape=jax.ShapeDtypeStruct((B, Hq, D), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name=f"paged_attn_{'bf16' if bits is None else f'kv{bits}'}",
-    )(block_table, pos, q4, k, k_s if quant else zero, v,
+    )(block_table, pos, q, k, k_s if quant else zero, v,
       v_s if quant else zero)
-    return out.reshape(B, Hq, D)
 
 
 def paged_attn_ref(q, k, k_s, v, v_s, pos, block_table, *,
@@ -262,14 +286,12 @@ def _paged_mla_kernel(bt_ref, pos_ref, ql_ref, qr_ref, cq_ref, cs_ref, r_ref,
 
     @pl.when(j == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, BIG_NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _init_state(m_ref, l_ref, acc_ref)
 
     ql = ql_ref[0].astype(jnp.float32)  # (H, C)
     qr = qr_ref[0].astype(jnp.float32)  # (H, dr)
     c = _dequant_block(cq_ref[0, :, 0],
-                       None if bits is None else cs_ref[0, :, 0], bits)
+                       None if bits is None else cs_ref[0], bits)
     r = r_ref[0, :, 0].astype(jnp.float32)  # (ps, dr) shared rope key
 
     s_lat = jax.lax.dot_general(ql, c, (((1,), (1,)), ((), ())),
@@ -278,22 +300,12 @@ def _paged_mla_kernel(bt_ref, pos_ref, ql_ref, qr_ref, cq_ref, cs_ref, r_ref,
                                  preferred_element_type=jnp.float32)
     s = (s_lat + s_rope) * scale  # (H, ps)
     kpos = j * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
-    valid = kpos <= pos_ref[b]
-    s = jnp.where(valid, s, BIG_NEG)
-
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-    p = jnp.where(valid, jnp.exp(s - m_new[:, None]), 0.0)
-    alpha = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1)
     # context accumulates in LATENT space: value rows ARE the c latents
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-        p, c, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
+    _softmax_update(m_ref, l_ref, acc_ref, slice(None), s, kpos <= pos_ref[b], c)
 
     @pl.when(j == nb - 1)
-    def _flush():
-        o_ref[0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[:, None]
+    def _done():
+        _flush(o_ref, l_ref, acc_ref)
 
 
 def paged_mla_attn_pallas(
@@ -307,12 +319,12 @@ def paged_mla_attn_pallas(
     *,
     bits: Optional[int],
     scale: float,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Returns the latent context (B, H, C) f32 — the caller applies W_uv.
     One grid step = one (slot, page); every head shares the page read."""
     B, H, C = q_lat.shape
-    P_, ps = c.shape[0], c.shape[1]
+    ps = c.shape[1]
     NB = block_table.shape[1]
     pos = jnp.asarray(pos, jnp.int32).reshape(B)
 
@@ -327,30 +339,24 @@ def paged_mla_attn_pallas(
             pl.BlockSpec((1, H, C), lambda b, j, bt, pos: (b, 0, 0)),
             pl.BlockSpec((1, H, q_rope.shape[-1]),
                          lambda b, j, bt, pos: (b, 0, 0)),
-            pl.BlockSpec((1, ps, 1, c.shape[-1]),
-                         lambda b, j, bt, pos: (bt[b, j], 0, 0, 0)),
-            pl.BlockSpec((1, ps, 1), lambda b, j, bt, pos: (bt[b, j], 0, 0))
-            if quant else pl.BlockSpec((1,), lambda b, j, bt, pos: (0,)),
-            pl.BlockSpec((1, ps, 1, r.shape[-1]),
-                         lambda b, j, bt, pos: (bt[b, j], 0, 0, 0)),
+            _page_spec(c), _page_spec(c_s if quant else None), _page_spec(r),
         ],
         out_specs=pl.BlockSpec((1, H, C), lambda b, j, bt, pos: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((H,), jnp.float32),
-            pltpu.VMEM((H,), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
             pltpu.VMEM((H, C), jnp.float32),
         ],
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, C), jnp.float32),
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name=f"paged_mla_attn_{'bf16' if bits is None else f'kv{bits}'}",
     )(block_table, pos, q_lat, q_rope, c, c_s if quant else zero, r)
-    return out
 
 
 def paged_mla_attn_ref(q_lat, q_rope, c, c_s, r, pos, block_table, *,

@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 from repro.core import pack as P
 
 BIG_NEG = -2.0e9
@@ -79,7 +77,7 @@ def qkv_decode_pallas(
     *,
     bits: int = 8,
     bs: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Returns (B, Hq, d)."""
     B, Hq, D = q.shape
@@ -114,7 +112,7 @@ def qkv_decode_pallas(
             pltpu.VMEM((1,), jnp.float32),
             pltpu.VMEM((1, D), jnp.float32),
         ],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name=f"qkv_decode_i{bits}",

@@ -14,10 +14,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 from repro.core import pack as P
 from repro.kernels.mpmm import _requant_block
+from repro.kernels.tuning import IllegalTile
 
 
 def _qntpack_kernel(phi_ref, rqv_ref, o_ref, *, y_bits: int):
@@ -31,12 +30,13 @@ def qntpack_pallas(
     *,
     y_bits: int,
     bm: int = 256,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     M, N = phi.shape
     ry = P.pack_ratio(y_bits)
     bm = min(bm, M)
-    assert M % bm == 0 and N % ry == 0
+    if M % bm:
+        raise IllegalTile(f"bm={bm} does not divide M={M}")
     return pl.pallas_call(
         functools.partial(_qntpack_kernel, y_bits=y_bits),
         grid=(M // bm,),
@@ -46,7 +46,7 @@ def qntpack_pallas(
         ],
         out_specs=pl.BlockSpec((bm, N // ry), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((M, N // ry), jnp.int8),
-        compiler_params=compat.CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
         name=f"qntpack_u{y_bits}",
     )(phi, rqv)

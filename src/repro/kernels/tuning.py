@@ -31,6 +31,12 @@ from typing import Callable, Optional, Sequence
 
 CACHE_FORMAT = "repro-tile-cache-v1"
 
+
+class IllegalTile(ValueError):
+    """A kernel was handed blocks that cannot tile its operands. The one
+    error :func:`autotune` treats as "skip this candidate"; a compiler error
+    is a fault and propagates."""
+
 #: The pre-registry hand-picked blocks (mpmm.py's VMEM working-set math).
 STATIC_DEFAULTS: dict[str, dict[str, int]] = {
     "mpmm": {"bm": 256, "bn": 256, "bk": 512},
@@ -316,7 +322,7 @@ def autotune(
     for tiles in cand:
         try:
             us = time_call(make_call(tiles), iters=iters, warmup=warmup)
-        except Exception as e:  # illegal tile for this shape — skip, never fatal
+        except IllegalTile as e:
             last_exc = e
             continue
         if us < best_us:

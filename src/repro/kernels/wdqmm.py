@@ -18,9 +18,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 from repro.core import pack as P
+from repro.kernels.tuning import IllegalTile
 
 
 def _wdqmm_kernel(x_ref, w_ref, eps_ref, o_ref, acc_ref, *,
@@ -51,14 +50,16 @@ def wdqmm_pallas(
     bm: int = 256,
     bn: int = 256,
     bk: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     rw = P.pack_ratio(w_bits)
     M, K = x.shape
     N = w_p.shape[0]
-    assert w_p.shape[1] * rw == K
-    bm, bn, bk = min(bm, M), min(bn, N), min(bk, K)
-    assert M % bm == 0 and N % bn == 0 and K % bk == 0 and bk % rw == 0
+    if w_p.shape[1] * rw != K:
+        raise ValueError(f"K mismatch: x gives {K}, w gives {w_p.shape[1] * rw}")
+    bm, bn, bk = min(bm, M), P.snap_block(bn, N, 1), P.snap_block(bk, K, rw)
+    if M % bm or N % bn or K % bk:
+        raise IllegalTile(f"blocks {(bm, bn, bk)} do not divide {(M, N, K)}")
     k_steps = K // bk
     return pl.pallas_call(
         functools.partial(_wdqmm_kernel, w_bits=w_bits, k_steps=k_steps),
@@ -71,7 +72,7 @@ def wdqmm_pallas(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name=f"wdqmm_i{w_bits}",

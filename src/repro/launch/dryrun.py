@@ -9,11 +9,15 @@ Usage:
 Artifacts: experiments/dryrun/<arch>__<shape>__<mesh>.json (incremental).
 """
 
-# The CPU container has one real device; the dry-run needs 512 placeholders.
-# These two lines MUST run before any other import (jax locks device count
-# on first init). Set here only — never globally (tests/benches see 1 device).
+# The dry-run is a projection on the CPU: 512 placeholder host devices, CPU
+# cost_analysis (until ROADMAP A2 moves it onto a described TPU topology).
+# These lines MUST run before any other import (jax locks platform and
+# device count on first init). Pinning the CPU also keeps this process and
+# the --all children it starts off any attached chip. Set here only — never
+# globally (tests/benches see 1 device).
 import os
 
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("XLA_FLAGS", "")

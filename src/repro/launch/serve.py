@@ -12,7 +12,7 @@ import argparse
 import jax
 import numpy as np
 
-from repro import configs
+from repro import compile_cache, configs
 from repro.core.policy import get_policy
 from repro.models import model as M
 from repro.serve import SamplingParams, ServeEngine, Tracer, write_exposition
@@ -70,6 +70,7 @@ def main():
                     help="write the final Prometheus exposition to a file")
     ap.add_argument("--smoke", action="store_true")
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = configs.get_arch(args.arch)
     if args.smoke:

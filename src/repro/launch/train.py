@@ -12,13 +12,14 @@ from __future__ import annotations
 
 import argparse
 import time
+from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import configs
+from repro import compile_cache, configs
 from repro.checkpoint import store
 from repro.configs.shapes import SHAPES, ShapeCfg
 from repro.core.policy import get_policy
@@ -35,6 +36,43 @@ def make_mesh(data: int, model: int, pod: int = 1) -> Mesh:
     if pod > 1:
         return Mesh(devs.reshape(pod, data, model), ("pod", "data", "model"))
     return Mesh(devs.reshape(data, model), ("data", "model"))
+
+
+class TrainSetup(NamedTuple):
+    init: Callable[[], Any]  # () -> train state, created sharded
+    step: Callable[[Any, Any], Any]  # (state, batch) -> (state, metrics)
+    state_shape: Any  # ShapeDtypeStruct tree of the state
+    state_shardings: Any
+    batch_shardings: Any
+
+
+def setup(cfg, policy, tcfg: T.TrainCfg, shape: ShapeCfg,
+          mesh: Mesh) -> TrainSetup:
+    """The sharded train state and step for ``mesh`` (FSDP parameters and
+    moments, data-parallel batch). ``init`` creates the state already
+    sharded (``init_train_state`` jitted with ``out_shardings``), so a state
+    larger than one device — bf16 params plus f32 Adam moments at full
+    width — never materializes on one device."""
+    env = MX.AxisEnv(mesh=mesh, fsdp=True)
+
+    def make():
+        return T.init_train_state(jax.random.key(0), cfg, policy, tcfg)
+
+    state_shape = jax.eval_shape(make)
+    pspecs = MX.param_specs(state_shape["params"], env)
+    sspecs = {"params": pspecs, "opt": {"m": pspecs, "v": pspecs, "step": P()}}
+    if "ef" in state_shape:
+        sspecs["ef"] = pspecs
+    sshard = MX.tree_shardings(sspecs, env)
+    bshard = MX.tree_shardings(MX.batch_specs(cfg, shape, env), env)
+    step = jax.jit(
+        T.make_train_step(cfg, policy, tcfg, impl="jnp"),
+        in_shardings=(sshard, bshard),
+        out_shardings=(sshard, None),
+        donate_argnums=(0,),
+    )
+    return TrainSetup(jax.jit(make, out_shardings=sshard), step, state_shape,
+                      sshard, bshard)
 
 
 def main():
@@ -54,6 +92,7 @@ def main():
     ap.add_argument("--smoke", action="store_true",
                     help="reduced config + tiny shape (CPU-runnable)")
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = configs.get_arch(args.arch)
     shape = SHAPES[args.shape]
@@ -68,40 +107,26 @@ def main():
     )
 
     mesh = make_mesh(args.mesh_data, args.mesh_model, args.mesh_pod)
-    env = MX.AxisEnv(mesh=mesh, fsdp=True)
     print(f"mesh {dict(mesh.shape)} arch={cfg.name} policy={policy.name}")
-
-    state = T.init_train_state(jax.random.key(0), cfg, policy, tcfg)
-    pspecs = MX.param_specs(state["params"], env)
-    sspecs = {"params": pspecs, "opt": {"m": pspecs, "v": pspecs, "step": P()}}
-    if "ef" in state:
-        sspecs["ef"] = pspecs
-    sshard = MX.tree_shardings(sspecs, env)
-    state = jax.device_put(state, sshard)
-    bspecs = MX.batch_specs(cfg, shape, env)
+    ts = setup(cfg, policy, tcfg, shape, mesh)
 
     start = 0
     ck = store.Checkpointer(args.ckpt, keep=3)
     if args.resume and store.latest_step(args.ckpt) is not None:
-        state, start = store.load(args.ckpt, jax.eval_shape(lambda: state),
-                                  shardings=sshard)
+        state, start = store.load(args.ckpt, ts.state_shape,
+                                  shardings=ts.state_shardings)
         print(f"resumed from step {start} (elastic reshard onto current mesh)")
+    else:
+        state = ts.init()
     latest = {"step": start, "state": state}
     ck.install_preemption_handler(lambda: (latest["step"], latest["state"]))
-
-    step_fn = jax.jit(
-        T.make_train_step(cfg, policy, tcfg, impl="jnp"),
-        in_shardings=(sshard, MX.tree_shardings(bspecs, env)),
-        out_shardings=(sshard, None),
-        donate_argnums=(0,),
-    )
 
     pipe = Pipeline(cfg, shape, start_step=start)
     mon = StepMonitor()
     for _ in range(start, args.steps):
         step_i, batch = next(pipe)
         t0 = time.perf_counter()
-        state, metrics = step_fn(state, jax.tree.map(jnp.asarray, batch))
+        state, metrics = ts.step(state, jax.tree.map(jnp.asarray, batch))
         jax.block_until_ready(metrics["loss"])
         slow = mon.observe(time.perf_counter() - t0)
         latest.update(step=step_i + 1, state=state)

@@ -139,24 +139,35 @@ def flash_attention(
     q_chunk: int = 512,
     kv_chunk: int = 512,
     q_offset: int = 0,
+    impl: ops.Impl = "auto",
 ) -> jax.Array:
     """Memory-linear chunked attention. Differentiable; the per-q-chunk body
     is rematerialized so training never stores S x S scores.
 
-    On a real TPU backend the forward dispatches to the Pallas flash kernel
-    (kernels/flash.py: grid-predicated causal/window schedule); the pure-JAX
-    path below is the CPU/dry-run/backward implementation."""
+    ``impl`` resolves like every kernel op (``auto``: Pallas on a TPU, jnp
+    elsewhere). ``pallas`` runs the Pallas flash kernel (kernels/flash.py:
+    grid-predicated causal/window schedule) on the shapes it takes; ``jnp``
+    runs the pure-JAX path below, which is also the training one: a
+    ``pallas_call`` has no transpose rule, and GSPMD cannot partition a
+    Mosaic kernel across a sharded train step, so train-mode callers pass
+    ``impl="jnp"``."""
     from repro import runtime_flags as RF
+    from repro.kernels import dispatch
 
-    if (jax.default_backend() == "tpu" and not RF.unrolled()
-            and q.shape[1] > 1):
-        from repro.kernels.flash import flash_mha_pallas
-
-        out = flash_mha_pallas(
+    if (dispatch.resolve_impl(impl) == "pallas" and not RF.unrolled()
+            and q.shape[1] > 1 and q_offset == 0
+            and v.shape[-1] == q.shape[-1]):
+        out = ops.flash_mha(
             q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
             v.transpose(0, 2, 1, 3), causal=causal, window=window,
-            bq=q_chunk, bk=kv_chunk, interpret=False)
+            bq=q_chunk, bk=kv_chunk)
         return out.transpose(0, 2, 1, 3).astype(q.dtype)
+    return _flash_jnp(q, k, v, causal=causal, window=window, q_chunk=q_chunk,
+                      kv_chunk=kv_chunk, q_offset=q_offset)
+
+
+def _flash_jnp(q, k, v, *, causal, window, q_chunk, kv_chunk, q_offset=0):
+    from repro import runtime_flags as RF
 
     B, Sq, Hq, D = q.shape
     _, Sk, Hkv, Dv = v.shape[0], v.shape[1], v.shape[2], v.shape[3]
@@ -417,7 +428,8 @@ def attn_apply(
     elif cache is None or prefill:
         # full-sequence: flash path. Prefill (cache_pos == 0) attends over the
         # freshly computed k/v while the quantized cache write happens above.
-        y = flash_attention(q, k, v, causal=causal, window=cfg.window)
+        y = flash_attention(q, k, v, causal=causal, window=cfg.window,
+                            impl="jnp" if mode == "train" else impl)
     else:
         # decode / cross-attn: q is short, keys long -> single-pass softmax
         groups = cfg.n_heads // k.shape[2]
@@ -560,7 +572,8 @@ def mla_apply(
         k_nope, v = kv[..., : cfg.d_nope], kv[..., cfg.d_nope :]
         k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope, (B, S, H, cfg.d_rope))], axis=-1)
         qf = jnp.concatenate([q_nope, q_rope], axis=-1)
-        y = flash_attention(qf, k, v, causal=True)
+        y = flash_attention(qf, k, v, causal=True,
+                            impl="jnp" if mode == "train" else impl)
     elif fused and S == 1:
         wkv_b = _mla_wkv_b_dense(params, cfg, lp).reshape(H, cfg.d_nope + cfg.d_v, cfg.kv_lora)
         w_uk, w_uv = wkv_b[:, : cfg.d_nope, :], wkv_b[:, cfg.d_nope :, :]

@@ -1,7 +1,8 @@
 """Roofline analysis from dry-run artifacts (EXPERIMENTS.md Sec. Roofline).
 
-Hardware constants (TPU v5e, per the assignment):
-  peak compute 197 TFLOP/s bf16/int8 per chip; HBM 819 GB/s; ICI ~50 GB/s/link.
+Hardware constants: the TPU v5e row of ``repro.devices.PEAKS`` (197
+TFLOP/s bf16 per chip — the dry-run programs are bf16; HBM 819 GB/s; ICI
+50 GB/s per link).
 
 The compiled SPMD module is the PER-DEVICE program, so cost_analysis flops /
 bytes and the HLO-parsed collective bytes are per-device quantities:
@@ -20,10 +21,13 @@ import json
 import os
 from typing import Optional
 
-PEAK_FLOPS = 197e12  # per chip, bf16/int8
-HBM_BW = 819e9  # B/s per chip
-ICI_BW = 50e9  # B/s per link
-V5E_HBM_BYTES = 16 * 1024**3
+from repro.devices import V5E_KIND, peaks
+
+_V5E = peaks(V5E_KIND)
+PEAK_FLOPS = _V5E["bf16_flops"]  # per chip
+HBM_BW = _V5E["hbm_bw"]  # B/s per chip
+ICI_BW = _V5E["ici_link_bw"]  # B/s per link
+V5E_HBM_BYTES = _V5E["hbm_bytes"]
 
 
 def cell_terms(rec: dict) -> Optional[dict]:

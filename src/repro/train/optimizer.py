@@ -88,14 +88,6 @@ def ef_state_init(params) -> dict:
     return jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
 
 
-def _axis_size(axis_name: str) -> int:
-    """Static mapped-axis size. ``jax.lax.axis_size`` only exists on newer
-    jax; ``psum(1, axis)`` constant-folds to the same int on 0.4.x."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 def _compress_allreduce_vec(v: jax.Array, axis_name: str) -> jax.Array:
     """Mean-all-reduce a flat fp32 vector with int8 on the wire.
 
@@ -104,7 +96,7 @@ def _compress_allreduce_vec(v: jax.Array, axis_name: str) -> jax.Array:
     Wire bytes: 2 x N x 1B vs 2 x N x 4B for a ring fp32 all-reduce (4x cut).
     Must run inside shard_map with ``axis_name`` bound.
     """
-    n_dev = _axis_size(axis_name)
+    n_dev = jax.lax.axis_size(axis_name)
     n = v.shape[0]
     pad = -n % n_dev
     vp = jnp.pad(v, (0, pad))

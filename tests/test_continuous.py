@@ -12,7 +12,8 @@ import jax
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 from repro import configs
 from repro.core.policy import get_policy
 from repro.models import model as M

@@ -17,6 +17,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def _run(code: str):
     env = dict(os.environ)
+    # the children run on 8 host devices, never on a chip the parent may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],

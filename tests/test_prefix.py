@@ -26,7 +26,8 @@ from repro.core.policy import get_policy
 from repro.kernels import paged_gather as PG
 from repro.serve import PrefixCache, Request, ServeEngine
 
-from tests._hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 jax.config.update("jax_platform_name", "cpu")
 

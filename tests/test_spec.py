@@ -34,7 +34,8 @@ from repro.serve import (
 )
 from repro.serve.spec import derive_w4_policy, requantize_params_w4
 
-from tests._hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 jax.config.update("jax_platform_name", "cpu")
 

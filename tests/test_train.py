@@ -57,6 +57,33 @@ def test_microbatch_equivalence():
         g1, g2)
 
 
+def test_launcher_setup_inits_sharded_state_equal_to_eager_init():
+    """launch/train.setup creates the state through a jit with
+    out_shardings (never whole on one device): the eager init's values (to
+    one bf16 rounding: the jit may fuse the init's scale-and-cast), placed
+    as the launcher's shardings say, and its step trains."""
+    from repro.launch import train as TL
+
+    tcfg = _tcfg()
+    ts = TL.setup(TINY, POLICY, tcfg, SHAPE, TL.make_mesh(1, 1))
+    state = ts.init()
+    eager = T.init_train_state(jax.random.key(0), TINY, POLICY, tcfg)
+    assert (jax.tree.structure(state) == jax.tree.structure(eager)
+            == jax.tree.structure(ts.state_shape))
+    for got, want, shd in zip(jax.tree.leaves(state), jax.tree.leaves(eager),
+                              jax.tree.leaves(ts.state_shardings)):
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   rtol=2**-7, atol=0)
+        assert got.sharding.is_equivalent_to(shd, got.ndim)
+    batch = jax.tree.map(jnp.asarray, make_batch(TINY, SHAPE, 0))
+    losses = []
+    for _ in range(3):
+        state, metrics = ts.step(state, batch)
+        losses.append(float(metrics["loss"]))
+    assert np.isfinite(losses).all() and losses[-1] < losses[0]
+
+
 def test_moe_training_runs():
     cfg = configs.reduced(configs.get_arch("granite-moe-1b-a400m"))
     tcfg = _tcfg()
