@@ -444,7 +444,6 @@ def run_trace_overhead(impl: str = "jnp", *, steps: int = 80,
 
     from repro import configs
     from repro.core.policy import get_policy
-    from repro.kernels import dispatch
     from repro.models import model as M
     from repro.serve import Tracer
 
@@ -454,26 +453,18 @@ def run_trace_overhead(impl: str = "jnp", *, steps: int = 80,
     rows = []
     for mode, backend, mixed in (("serialized", "slot", False),
                                  ("continuous", "paged", True)):
-        # the per-op kernel timer is process-global once any traced engine
-        # has existed — force it off so the baseline is a true untraced run
-        dispatch.set_timing(False)
         eng_off = _engine(params, cfg, policy, backend, impl, mixed)
-        _warm(eng_off)  # compile with timing OFF: the baseline jits are the
-        # exact untraced production artifacts
+        _warm(eng_off)
         tracer = Tracer()
         eng_on = _engine(params, cfg, policy, backend, impl, mixed,
                          tracer=tracer)
         _warm(eng_on)
-        # timing stays ON (the traced engine's production state) through the
-        # interleaved window below: it only acts at jit-trace time, and the
-        # baseline engine's jits are already compiled, so the untraced
-        # samples are unaffected
         offs, ons = [], []
         for _ in range(repeats):
             o, n = _paired_step_s(eng_off, eng_on, steps=steps)
             offs.append(o)
             ons.append(n)
-        dispatch.set_timing(False)
+        eng_on.close()  # stop watching garbage collections
         off_s = float(np.median(offs))
         on_s = float(np.median(ons))
         ratio = float(np.median([on / off for on, off in zip(ons, offs)]))

@@ -76,50 +76,6 @@ _REGISTRY: dict[KernelKey, KernelEntry] = {}
 #: ``serve.engine.ServeEngine.kernel_stats()`` snapshots this.
 DISPATCH_COUNTS: collections.Counter = collections.Counter()
 
-#: Opt-in per-OP wall-clock accumulation (seconds, process-wide), keyed by
-#: op name — enabled via :func:`set_timing` (the serving engine flips it on
-#: when a tracer is attached). Off by default because the wrapper's
-#: perf_counter pair sits on the dispatch path; when disabled, :func:`lookup`
-#: returns the registered entry untouched (zero overhead). NOTE on meaning:
-#: under jit, ``entry.fn`` runs once per trace — the time recorded is
-#: TRACE/interpret-mode cost, not steady-state device time; on the jnp/eager
-#: path it is honest wall clock. Either way it attributes "where did the
-#: host spend time building this step" per op, which is what the kernel rows
-#: in ``metrics()`` report.
-DISPATCH_SECONDS: collections.Counter = collections.Counter()
-
-_TIMING = False
-
-
-def set_timing(enabled: bool) -> bool:
-    """Enable/disable per-op wall-clock accumulation; returns prior state."""
-    global _TIMING
-    prev = _TIMING
-    _TIMING = bool(enabled)
-    return prev
-
-
-def timing_enabled() -> bool:
-    return _TIMING
-
-
-def _timed(entry: KernelEntry) -> KernelEntry:
-    """A copy of ``entry`` whose ``fn`` records wall clock into
-    ``DISPATCH_SECONDS[op]``. Built per lookup only while timing is on —
-    entries themselves stay pristine in the registry."""
-    import time
-
-    inner, op = entry.fn, entry.key.op
-
-    def fn(*args, **kwargs):
-        t0 = time.perf_counter()
-        try:
-            return inner(*args, **kwargs)
-        finally:
-            DISPATCH_SECONDS[op] += time.perf_counter() - t0
-
-    return dataclasses.replace(entry, fn=fn)
-
 IMPLS = ("pallas", "jnp")
 
 #: KV-cache storage widths the quantizer emits (models.attention.kv_quantize):
@@ -175,7 +131,7 @@ def lookup(
             f"outside the library. Registered {op} cells: {have}"
         )
     DISPATCH_COUNTS[key] += 1
-    return _timed(entry) if _TIMING else entry
+    return entry
 
 
 def registered_keys(op: Optional[str] = None) -> list[KernelKey]:
@@ -201,13 +157,6 @@ def dispatch_stats() -> dict[str, int]:
 
 def reset_dispatch_counts() -> None:
     DISPATCH_COUNTS.clear()
-    DISPATCH_SECONDS.clear()
-
-
-def dispatch_seconds() -> dict[str, float]:
-    """Snapshot of accumulated per-op wall clock (empty unless
-    :func:`set_timing` was enabled), sorted by op."""
-    return {op: DISPATCH_SECONDS[op] for op in sorted(DISPATCH_SECONDS)}
 
 
 def validate_coverage() -> None:

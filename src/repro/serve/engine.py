@@ -135,11 +135,6 @@ class KernelStatsAccumulator:
     def __init__(self):
         self._counts: collections.Counter = collections.Counter()
         self._last = dict(dispatch.DISPATCH_COUNTS)
-        # per-OP wall clock (dispatch.DISPATCH_SECONDS, populated only while
-        # dispatch.set_timing is on) — harvested with the same reset-robust
-        # delta discipline as the counts
-        self._seconds: collections.Counter = collections.Counter()
-        self._last_s = dict(dispatch.DISPATCH_SECONDS)
 
     def harvest(self) -> None:
         cur = dict(dispatch.DISPATCH_COUNTS)
@@ -151,13 +146,6 @@ class KernelStatsAccumulator:
             if d > 0:
                 self._counts[k] += d
         self._last = cur
-        cur_s = dict(dispatch.DISPATCH_SECONDS)
-        for op, v in cur_s.items():
-            prev = self._last_s.get(op, 0.0)
-            d = v - prev if v >= prev else v
-            if d > 0:
-                self._seconds[op] += d
-        self._last_s = cur_s
 
     def stats(self) -> dict[str, int]:
         self.harvest()
@@ -165,19 +153,13 @@ class KernelStatsAccumulator:
                                              key=lambda kv: str(kv[0]))}
 
     def op_stats(self) -> dict:
-        """Per-OP rollup for ``metrics()``: ``kernels/<op>_calls`` (cell
-        counts summed over the op's permutations) and ``kernels/<op>_s``
-        (accumulated wall clock; 0.0 unless timing was enabled — the engine
-        flips ``dispatch.set_timing`` on when a tracer is attached)."""
+        """Per-OP rollup for ``metrics()``: ``kernels/<op>_calls``, the cell
+        counts summed over the op's permutations."""
         self.harvest()
         calls: collections.Counter = collections.Counter()
         for k, v in self._counts.items():
             calls[k.op] += v
-        out: dict = {}
-        for op in sorted(set(calls) | set(self._seconds)):
-            out[f"kernels/{op}_calls"] = calls.get(op, 0)
-            out[f"kernels/{op}_s"] = float(self._seconds.get(op, 0.0))
-        return out
+        return {f"kernels/{op}_calls": calls[op] for op in sorted(calls)}
 
 
 class ServeEngine:
@@ -199,11 +181,8 @@ class ServeEngine:
                  trace: Optional[Tracer] = None):
         self.params, self.cfg, self.policy = params, cfg, policy
         #: optional event sink (serve/trace.py). None = zero overhead: every
-        #: emission site is behind an `is not None` check, and the per-op
-        #: kernel timer stays off.
+        #: emission site is behind an `is not None` check.
         self.trace = trace
-        if trace is not None:
-            dispatch.set_timing(True)
         # fused decode default-on where the attn_decode bench gate holds
         # (>= 1.1x on every measured KV dtype; benchmarks/lm_serving.py
         # run_attn_decode asserts greedy token-equality fused vs unfused).
@@ -242,15 +221,25 @@ class ServeEngine:
             nxt = M.sample_tokens(logits[:, -1], *samp)
             return nxt, logits, new_caches
 
+        # every program is jitted from a function named for it, so a profiler
+        # trace shows it as jit_<name>(<hash>): serve_decode, serve_sample,
+        # serve_mixed_step, serve_decode_step, serve_spec_draft,
+        # serve_spec_verify (and the prefiller's serve_prefill_*)
         if self.cache.paged:
-            self._decode = jax.jit(
-                lambda p, tok, pos, bt, caches, samp: decode_and_sample(
-                    p, tok, pos, caches, samp, bt=bt))
+            def serve_decode(p, tok, pos, bt, caches, samp):
+                return decode_and_sample(p, tok, pos, caches, samp, bt=bt)
         else:
-            self._decode = jax.jit(decode_and_sample)
+            def serve_decode(p, tok, pos, caches, samp):
+                return decode_and_sample(p, tok, pos, caches, samp)
+        self._decode = jax.jit(serve_decode)
+
         # the SAME sampler, traced once more at B=1 for the prefill's
         # last-token logits (the first output token of every request)
-        self._sample = jax.jit(M.sample_tokens)
+        def serve_sample(logits, temps, top_ks, top_ps, seeds, counters):
+            return M.sample_tokens(logits, temps, top_ks, top_ps, seeds,
+                                   counters)
+
+        self._sample = jax.jit(serve_sample)
         self.prefiller = make_prefiller(
             prefill, params, cfg, policy, impl=impl, chunk=prefill_chunk,
             step_fn=lambda toks: self._step(toks)[1], n_slots=n_slots,
@@ -316,16 +305,23 @@ class ServeEngine:
                 return nxt, new_caches
 
             if self.cache.paged:
-                self._mixed = jax.jit(
-                    lambda p, toks, chain, uc, pos, nr, bt, caches, samp:
-                    mixed_and_sample(p, toks, chain, uc, pos, nr, caches,
-                                     samp, bt=bt))
-                self._chain_decode = jax.jit(
-                    lambda p, chain, pos, bt, caches, samp:
-                    chain_and_sample(p, chain, pos, caches, samp, bt=bt))
+                def serve_mixed_step(p, toks, chain, uc, pos, nr, bt, caches,
+                                     samp):
+                    return mixed_and_sample(p, toks, chain, uc, pos, nr,
+                                            caches, samp, bt=bt)
+
+                def serve_decode_step(p, chain, pos, bt, caches, samp):
+                    return chain_and_sample(p, chain, pos, caches, samp, bt=bt)
             else:
-                self._mixed = jax.jit(mixed_and_sample)
-                self._chain_decode = jax.jit(chain_and_sample)
+                def serve_mixed_step(p, toks, chain, uc, pos, nr, caches,
+                                     samp):
+                    return mixed_and_sample(p, toks, chain, uc, pos, nr,
+                                            caches, samp)
+
+                def serve_decode_step(p, chain, pos, caches, samp):
+                    return chain_and_sample(p, chain, pos, caches, samp)
+            self._mixed = jax.jit(serve_mixed_step)
+            self._chain_decode = jax.jit(serve_decode_step)
 
         # --- speculative decoding (serve/spec.py) --------------------------
         self.spec = make_spec(spec)
@@ -370,11 +366,12 @@ class ServeEngine:
                 return drafts.T, caches
 
             if self.spec.shares_cache and self.cache.paged:
-                self._spec_draft = jax.jit(
-                    lambda p, tok0, pos, bt, caches, samp: draft_loop(
-                        p, tok0, pos, caches, samp, bt=bt))
+                def serve_spec_draft(p, tok0, pos, bt, caches, samp):
+                    return draft_loop(p, tok0, pos, caches, samp, bt=bt)
             else:
-                self._spec_draft = jax.jit(draft_loop)
+                def serve_spec_draft(p, tok0, pos, caches, samp):
+                    return draft_loop(p, tok0, pos, caches, samp)
+            self._spec_draft = jax.jit(serve_spec_draft)
 
             spec_ps = self.cache.page_size if self.cache.paged else None
 
@@ -384,11 +381,12 @@ class ServeEngine:
                     impl=impl, block_tables=bt, page_size=spec_ps)
 
             if self.cache.paged:
-                self._spec_verify = jax.jit(
-                    lambda p, toks, pos, nr, bt, caches, samp: verify(
-                        p, toks, pos, nr, caches, samp, bt=bt))
+                def serve_spec_verify(p, toks, pos, nr, bt, caches, samp):
+                    return verify(p, toks, pos, nr, caches, samp, bt=bt)
             else:
-                self._spec_verify = jax.jit(verify)
+                def serve_spec_verify(p, toks, pos, nr, caches, samp):
+                    return verify(p, toks, pos, nr, caches, samp)
+            self._spec_verify = jax.jit(serve_spec_verify)
 
         # metrics accumulators
         self._decode_steps = 0
@@ -407,6 +405,8 @@ class ServeEngine:
         self._run_t0: Optional[float] = None  # set while a step is active
         self._next_rid = 0
         self._closed = False
+        if trace is not None:
+            trace.watch_gc()  # host.gc spans; close() stops watching
 
     # --- kernel-matrix observability --------------------------------------
 
@@ -541,6 +541,8 @@ class ServeEngine:
             if r is not None:
                 self._release(s, CANCELLED)
         self._tickets.clear()  # in-flight steps: nobody left to emit for
+        if self.trace is not None:
+            self.trace.unwatch_gc()
         self._closed = True
 
     # --- request lifecycle: the loop ----------------------------------------
@@ -661,7 +663,7 @@ class ServeEngine:
         elif self.slot_remaining[slot] <= 0:
             self._release(slot, DONE)
 
-    def _admit(self) -> None:
+    def _admit(self) -> int:
         """Admit waiting requests into free capacity (continuous batching:
         admission runs between decode steps, while other slots decode).
 
@@ -670,20 +672,22 @@ class ServeEngine:
         and its admission-cost metric (the prefix backend charges only the
         UNMATCHED pages). The FIRST output token is sampled here from the
         prefill's own last-token logits, through the same batched sampler
-        the decode step fuses (counter 0 of the request's PRNG stream)."""
+        the decode step fuses (counter 0 of the request's PRNG stream).
+        Returns how many requests it admitted."""
         fits = lambda r: self.cache.can_admit(  # noqa: E731
             len(r.prompt) + r.max_new, prompt=r.prompt)
         cost = lambda r: self.cache.admission_cost(  # noqa: E731
             len(r.prompt) + r.max_new, prompt=r.prompt)
+        admitted = 0
         while self.scheduler.pending():
             req = self.scheduler.next_request(fits, cost)
             if req is None:  # defensive: a custom scheduler declined to pick
-                return
+                return admitted
             slot = self.cache.acquire(len(req.prompt) + req.max_new,
                                       prompt=req.prompt)
             if slot is None:  # no slot / page budget: requeue at the front
                 self.scheduler.requeue(req)
-                return
+                return admitted
             req.status = ACTIVE
             req.slot = slot
             req.t_admit = time.perf_counter()
@@ -702,6 +706,7 @@ class ServeEngine:
             self.slot_req[slot] = req
             self.slot_remaining[slot] = req.max_new
             self._progress += 1
+            admitted += 1
             if self.mixed:
                 # continuous mode: no blocking prefill here — park a cursor
                 # and let the mixed steps carry the prompt in under the
@@ -735,6 +740,7 @@ class ServeEngine:
                 jnp.float32([p.top_p]), jnp.uint32([p.seed]),
                 jnp.int32([0]))
             self._emit(slot, int(np.asarray(first)[0]))
+        return admitted
 
     def _active(self) -> bool:
         return any(r is not None for r in self.slot_req)
@@ -769,7 +775,20 @@ class ServeEngine:
                  if self._prefilling else [])
         if not decode_lanes and not allot:
             return False
-        t0 = time.perf_counter()
+        sp = None
+        if self.trace is not None:
+            # what the step's decode attention reads, counted at dispatch:
+            # each lane's cached context and the pages holding it with the
+            # token this step writes
+            ctx = [int(self.cache.pos[s]) for s in decode_lanes]
+            pages = ({"decode_pages": sum([-(-(n + 1) // self.cache.page_size)
+                                           for n in ctx])}
+                     if self.cache.paged else {})
+            sp = self.trace.scope(
+                "mixed_step" if allot else "decode_step", cat="engine",
+                step=self._decode_steps, decode_ctx_tokens=sum(ctx), **pages)
+        # a step's prefill_chunk spans share its start (readers pair them)
+        t0 = time.perf_counter() if sp is None else sp.t0_ns * 1e-9
         #: (slot, request, emits): emits=False for non-final prefill chunks
         lanes: list[tuple[int, Request, bool]] = []
         if allot:
@@ -781,9 +800,10 @@ class ServeEngine:
             use_chain = np.zeros(self.n_slots, bool)
             writes: list[tuple[int, int]] = []
             commits: list[tuple[int, Request]] = []
-            chunkinfo: list[tuple[int, int, int, int]] = []
+            chunkinfo: list[tuple[int, int, int, int, int]] = []
             for cur, n in allot:
                 s = cur.slot
+                off = cur.off
                 chunk = cur.take(n)
                 host_toks[s, :len(chunk)] = chunk
                 n_real[s] = len(chunk)
@@ -791,7 +811,8 @@ class ServeEngine:
                 writes.append((s, len(chunk)))
                 # the final chunk's lane emits the request's FIRST token
                 lanes.append((s, cur.req, cur.done))
-                chunkinfo.append((s, cur.req.rid, cur.chunks - 1, len(chunk)))
+                chunkinfo.append((s, cur.req.rid, cur.chunks - 1, off,
+                                  len(chunk)))
                 if cur.done:
                     commits.append((s, cur.req))
             for s in decode_lanes:
@@ -820,10 +841,10 @@ class ServeEngine:
                 # (device work overlaps by design); chunks of one request
                 # stay sequential because steps are sequential host-side
                 t1 = time.perf_counter()
-                for s, rid, idx, n in chunkinfo:
+                for s, rid, idx, off, n in chunkinfo:
                     self.trace.span(f"prefill_chunk[{idx}]", cat="request",
                                     t0=t0, t1=t1, track=slot_track(s),
-                                    rid=rid, slot=s, tokens=n)
+                                    rid=rid, slot=s, tokens=n, offset=off)
             for s, n in writes:
                 self.cache.advance(s, n)
             for s, req in commits:
@@ -867,19 +888,14 @@ class ServeEngine:
         self._tickets.append((nxt, lanes))
         self._progress += 1
         now = time.perf_counter()
-        if self.trace is not None:
+        if sp is not None:
             # the engine-pipeline view of this dispatch: budget split,
             # in-flight depth, and the step's cache-counter deltas (pages
             # drawn / COW copies / evictions attributed to THIS step)
-            n_prefill = len(allot)
-            self.trace.span(
-                "mixed_step" if allot else "decode_step", cat="engine",
-                t0=t0, t1=now, track=ENGINE_TRACK,
-                step=self._decode_steps - 1,
-                decode_lanes=len(decode_lanes), prefill_lanes=n_prefill,
-                prefill_tokens=int(sum(n for _, n in allot)),
-                budget=self.mixed_budget, inflight=len(self._tickets),
-                **self._cache_deltas())
+            sp.close(decode_lanes=len(decode_lanes), prefill_lanes=len(allot),
+                     prefill_tokens=int(sum(n for _, n in allot)),
+                     budget=self.mixed_budget, inflight=len(self._tickets),
+                     **self._cache_deltas())
             self.trace.counter("queue_depth", self.scheduler.pending(),
                                ts=now)
             self.trace.counter("inflight", len(self._tickets), ts=now)
@@ -891,21 +907,27 @@ class ServeEngine:
         host sync. Lanes whose request turned over since dispatch (stop
         hit, cancel, slot reuse) are dropped by identity check."""
         nxt, lanes = self._tickets.popleft()
-        t0 = time.perf_counter()
-        nxt = np.asarray(nxt)  # blocks until the step's results are ready
+        sp = None
         if self.trace is not None:
             # the sync-wait itself: a long retire right after short
             # dispatches is the pipeline-bubble signature
-            self.trace.span("retire", cat="engine", t0=t0,
-                            t1=time.perf_counter(), track=ENGINE_TRACK,
-                            lanes=len(lanes), inflight=len(self._tickets))
+            sp = self.trace.scope("retire", cat="engine", lanes=len(lanes),
+                                  inflight=len(self._tickets))
+        nxt = np.asarray(nxt)  # blocks until the step's results are ready
+        if sp is not None:
+            sp.close()
+            sp = self.trace.scope("serve.emit", cat="engine")
         self._progress += 1
+        tokens = 0
         for s, req, emits in lanes:
             if not emits:
                 continue
             if self.slot_req[s] is not req or req.status != ACTIVE:
                 continue  # released after this step was issued: speculative
             self._emit(s, int(nxt[s]))
+            tokens += 1
+        if sp is not None:
+            sp.close(tokens=tokens)
 
     # --- speculative decoding: the round ------------------------------------
 
@@ -1045,19 +1067,26 @@ class ServeEngine:
             raise RuntimeError("engine is closed")
         t0 = time.perf_counter()
         self._run_t0 = t0
+        sp = None
+        if self.trace is not None:
+            sp = self.trace.scope(
+                "serve.step", cat="engine", step=self._decode_steps,
+                mode="continuous" if self.mixed else "serialized")
         try:
-            if self.mixed:
-                if len(self._tickets) >= self.inflight_depth:
-                    self._retire_one()
+            if self.mixed and len(self._tickets) >= self.inflight_depth:
+                self._retire_one()
+            if sp is None or not self.scheduler.pending():
                 self._admit()
+            else:
+                admit = self.trace.scope("serve.admit", cat="engine")
+                admit.close(admitted=self._admit())
+            if self.mixed:
                 if not self._dispatch() and self._tickets:
                     self._retire_one()
             elif self.spec is not None:
-                self._admit()
                 if self._active():
                     self._spec_round()
             else:
-                self._admit()
                 if self._active():
                     # one decode step for every active slot: feed each
                     # slot's last generated token (never prompt[-1] —
@@ -1090,6 +1119,8 @@ class ServeEngine:
                         self.trace.counter("queue_depth",
                                            self.scheduler.pending(), ts=now)
         finally:
+            if sp is not None:
+                sp.close()
             self._serve_seconds += time.perf_counter() - t0
             self._run_t0 = None
         return bool(self.scheduler.pending() or self._active()
@@ -1200,8 +1231,7 @@ class ServeEngine:
             "step_ema_s": self.monitor.ema or 0.0,
             "stragglers": self.monitor.stragglers,
             "scheduler": self.scheduler.name,
-            # per-op kernel rollup (kernels/<op>_calls always; _s accumulates
-            # only while a tracer has per-op timing enabled)
+            # per-op kernel rollup: kernels/<op>_calls
             **self._kstats.op_stats(),
             # ring-buffer health when a tracer is attached (dropped > 0
             # means the trace is truncated — resize Tracer(capacity=...))

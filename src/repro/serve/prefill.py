@@ -36,6 +36,7 @@ by this request or mapped from a shared page.
 
 from __future__ import annotations
 
+import functools
 import time
 from typing import Callable, Optional
 
@@ -117,24 +118,23 @@ class ChunkedPrefill:
         # vocab-head matmul); the final chunk also returns last-token logits.
         # `ref` is the request's cache address: slot index (dense) or the
         # slot's block-table row (paged) — same argument slot either way.
+        # Named functions: a profiler trace shows the programs as
+        # jit_serve_prefill_last / jit_serve_prefill_chunk.
         if page_size is None:
-            self._fn_last = jax.jit(
-                lambda p, toks, ref, pos, last, caches: M.prefill_into_slot(
-                    p, toks, ref, pos, caches, cfg, policy, last_idx=last,
-                    impl=impl))
-            self._fn_mid = jax.jit(
-                lambda p, toks, ref, pos, caches: M.prefill_into_slot(
-                    p, toks, ref, pos, caches, cfg, policy, head=False,
-                    impl=impl))
+            into = M.prefill_into_slot
         else:
-            self._fn_last = jax.jit(
-                lambda p, toks, ref, pos, last, caches: M.prefill_into_pages(
-                    p, toks, ref, pos, caches, cfg, policy, last_idx=last,
-                    page_size=page_size, impl=impl))
-            self._fn_mid = jax.jit(
-                lambda p, toks, ref, pos, caches: M.prefill_into_pages(
-                    p, toks, ref, pos, caches, cfg, policy, head=False,
-                    page_size=page_size, impl=impl))
+            into = functools.partial(M.prefill_into_pages, page_size=page_size)
+
+        def serve_prefill_last(p, toks, ref, pos, last, caches):
+            return into(p, toks, ref, pos, caches, cfg, policy, last_idx=last,
+                        impl=impl)
+
+        def serve_prefill_chunk(p, toks, ref, pos, caches):
+            return into(p, toks, ref, pos, caches, cfg, policy, head=False,
+                        impl=impl)
+
+        self._fn_last = jax.jit(serve_prefill_last)
+        self._fn_mid = jax.jit(serve_prefill_chunk)
 
     @staticmethod
     def supports(cfg: ArchConfig) -> bool:
@@ -146,7 +146,8 @@ class ChunkedPrefill:
         Returns the last real prompt token's logits (1, 1, V). Tokens the
         cache already holds (``cache.pos[slot]`` > 0: a matched shared
         prefix) are skipped — only the suffix is chunked through the jits."""
-        prompt = prompt[int(cache.pos[slot]):]
+        start = int(cache.pos[slot])
+        prompt = prompt[start:]
         S = len(prompt)
         logits = None
         off = 0
@@ -177,7 +178,7 @@ class ChunkedPrefill:
                 self.tracer.span(
                     f"prefill_chunk[{idx}]", cat="request", t0=t0,
                     t1=time.perf_counter(), track=slot + 1,
-                    rid=rid, slot=slot, tokens=n)
+                    rid=rid, slot=slot, tokens=n, offset=start + off)
             off += n
             idx += 1
         return logits

@@ -1,7 +1,7 @@
 """Prometheus-style text exposition of ``ServeEngine.metrics()``.
 
 ``metrics()`` returns a flat dict whose keys are slash-namespaced
-(``slo/ttft_p95_s``, ``cache/pages_free``, ``kernels/matmul_s``, ...) and
+(``slo/ttft_p95_s``, ``cache/pages_free``, ``kernels/mpmm_calls``, ...) and
 whose values are numbers, strings, or bools. Prometheus metric names
 forbid ``/`` and most punctuation, so the renderer maps every key to a
 sanitized ``repro_``-prefixed gauge name AND preserves the exact original

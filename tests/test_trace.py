@@ -4,11 +4,14 @@ completion, max_new=1, stop sequences, mid-decode and queued cancels); the
 Chrome export must be valid ``trace_event`` JSON with per-slot + engine
 tracks; tracing on must leave token streams BIT-IDENTICAL to tracing off
 on all three cache backends (serialized and continuous); the ring buffer
-must stay bounded; the Prometheus exposition must round-trip every
-``metrics()`` key through a real HTTP scrape; and the satellite pieces —
-LatencyHistogram mean/merge, per-op kernel timing — hold their contracts.
+must stay bounded; live spans must reach a JAX profiler session on the
+tracer's own clock (and an untraced engine must write none); the
+Prometheus exposition must round-trip every ``metrics()`` key through a
+real HTTP scrape; and LatencyHistogram mean/merge hold their contracts.
 """
 
+import gc
+import glob
 import json
 import urllib.request
 
@@ -18,7 +21,6 @@ import pytest
 
 from repro import configs
 from repro.core.policy import get_policy
-from repro.kernels import dispatch
 from repro.models import model as M
 from repro.serve import (
     LatencyHistogram,
@@ -46,14 +48,6 @@ BACKENDS = {
 @pytest.fixture(scope="module")
 def params():
     return M.init_params(jax.random.key(3), TINY, POLICY, mode="serve")
-
-
-@pytest.fixture(autouse=True)
-def _timing_off():
-    """Engine construction with a tracer flips the process-global per-op
-    kernel timer on; leave no cross-test residue."""
-    yield
-    dispatch.set_timing(False)
 
 
 def _engine(params, *, backend="slot", mixed=False, **kw):
@@ -358,32 +352,138 @@ def test_chrome_timestamps_are_microseconds_from_t0(params):
     assert rec["dur"] == pytest.approx(2000.0)
 
 
-# ------------------------------------------------ kernel timing
-
-
-def test_kernel_timing_accumulates_only_when_enabled(params):
-    prior = dispatch.set_timing(False)
-    try:
-        base = dict(dispatch.DISPATCH_SECONDS)
-        _engine(params).run(_requests(lengths=(3,)))
-        assert dict(dispatch.DISPATCH_SECONDS) == base  # off: untouched
-        eng = _engine(params, trace=Tracer())
-        eng.run(_requests(lengths=(3,)))
-        m = eng.metrics()
-        assert m["kernels/mpmm_calls"] > 0
-        assert m["kernels/mpmm_s"] > 0.0
-    finally:
-        dispatch.set_timing(prior)
+# ------------------------------------------------ kernel call counts
 
 
 def test_kernel_op_stats_in_metrics_without_tracer(params):
-    eng = _engine(params)
-    eng.run(_requests(lengths=(3,)))
-    m = eng.metrics()
-    # calls are counted regardless; seconds stay zero with timing off
-    assert m["kernels/mpmm_calls"] > 0
-    assert m["kernels/mpmm_s"] == 0.0
-    assert "trace/events_emitted" not in m  # no tracer, no trace gauges
+    """Kernel rows count calls, with a tracer or without; no row reads as
+    a time."""
+    for trace in (None, Tracer()):
+        eng = _engine(params, trace=trace)
+        eng.run(_requests(lengths=(3,)))
+        m = eng.metrics()
+        assert m["kernels/mpmm_calls"] > 0
+        assert not [k for k in m if k.startswith("kernels/")
+                    and not k.endswith("_calls")]
+        assert ("trace/events_emitted" in m) is (trace is not None)
+        eng.close()
+
+
+# ------------------------------------------------ live spans in a profile
+
+MIRRORED = ("serve.step", "serve.admit", "mixed_step", "decode_step",
+            "retire", "serve.emit")
+
+
+def _profile(tmp_path, fn):
+    """Run ``fn`` under a JAX profiler session; the host events of its
+    trace as (name, start_ns, stats) plus every host event name."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    pd = jax.profiler.ProfileData.from_file(path)
+    events = [(e.name, e.start_ns, dict(e.stats)) for plane in pd.planes
+              if plane.name.startswith("/host") for line in plane.lines
+              for e in line.events]
+    return events
+
+
+def test_spans_mirror_into_the_profiler_on_one_clock(params, tmp_path):
+    """Every live span opens a profiler annotation with the ring event's
+    args, stamped ``pc_ns`` = the ring event's start; the engine's programs
+    run under their own names."""
+    tr = Tracer()
+    eng = _engine(params, backend="paged", mixed=True, mixed_budget=4,
+                  prefill_chunk=4, trace=tr)
+    events = _profile(tmp_path, lambda: eng.run(_requests()))
+    eng.close()
+    ring = [e for e in tr.events() if e.name in MIRRORED]
+    anns = [e for e in events if e[0] in MIRRORED]
+    assert {e[0] for e in anns} == set(MIRRORED)
+    assert len(anns) == len(ring)
+    offsets = []
+    for name, start_ns, stats in anns:
+        ev = min(ring, key=lambda e: abs(e.ts * 1e9 - stats["pc_ns"]))
+        assert ev.name == name
+        assert abs(ev.ts * 1e9 - stats["pc_ns"]) < 1e3  # within 1 us
+        if "step" in ev.args:
+            assert stats["step"] == ev.args["step"]
+        offsets.append(start_ns - stats["pc_ns"])
+    # one offset ties the two clocks; it holds across the run
+    assert max(offsets) - min(offsets) < 10e6
+    names = {e[0] for e in events}
+    assert "PjitFunction(serve_mixed_step)" in names
+    assert "PjitFunction(serve_decode_step)" in names
+
+
+def test_untraced_engine_annotates_nothing_and_leaves_gc_alone(params,
+                                                               tmp_path):
+    gc.collect()  # tracers of earlier tests' engines stop watching
+    callbacks = list(gc.callbacks)
+    eng = _engine(params, backend="paged", mixed=True, mixed_budget=4,
+                  prefill_chunk=4)
+    assert gc.callbacks == callbacks
+    events = _profile(tmp_path, lambda: eng.run(_requests()))
+    assert not [e for e in events if e[0] in MIRRORED + ("host.gc",)]
+    assert "PjitFunction(serve_mixed_step)" in {e[0] for e in events}
+
+
+def test_gc_collection_recorded_as_host_gc_span(params):
+    tr = Tracer()
+    eng = _engine(params, trace=tr)
+    assert tr._gc_cb in gc.callbacks
+    eng.submit(np.arange(1, 6, dtype=np.int32), SamplingParams(max_new=3),
+               on_token=lambda rid, tok: gc.collect(1))
+    eng.drain()
+    spans = [e for e in tr.events() if e.name == "host.gc"]
+    assert spans and all(e.cat == "host" and e.ph == "X" for e in spans)
+    assert {e.args["gen"] for e in spans} >= {1}
+    assert all(e.args["collected"] >= 0 for e in spans)
+    assert eng.metrics()["trace/gc_pause_s"] >= sum(e.dur for e in spans) > 0
+    eng.close()
+    assert tr._gc_cb not in gc.callbacks
+    n = len(tr.events())
+    gc.collect(1)
+    assert len(tr.events()) == n
+
+
+def test_step_and_chunk_counts_follow_request_lengths(params):
+    """``decode_ctx_tokens`` / ``decode_pages`` on the dispatch spans and
+    each chunk's ``offset`` are what the requests' lengths imply: a
+    request of prompt L and max_new N decodes N - 1 steps at cached
+    contexts L .. L + N - 2; its chunks tile its prompt."""
+    ps = BACKENDS["paged"]["page_size"]
+    reqs = _requests(lengths=(3, 9, 21, 2), max_new=5)
+    for mixed in (False, True):
+        tr = Tracer()
+        eng = _engine(params, backend="paged", mixed=mixed, trace=tr,
+                      prefill_chunk=4, **(dict(mixed_budget=4) if mixed else {}))
+        eng.run(_requests(lengths=(3, 9, 21, 2), max_new=5))
+        eng.close()
+        for rid, evs in tr.request_events().items():
+            chunks = sorted((e for e in evs if e.name.startswith("prefill_chunk[")),
+                            key=lambda e: int(e.name[14:-1]))
+            offs = [e.args["offset"] for e in chunks]
+            lens = [e.args["tokens"] for e in chunks]
+            assert offs == [sum(lens[:i]) for i in range(len(lens))]
+            assert offs[-1] + lens[-1] == len(reqs[rid].prompt)
+        if not mixed:
+            continue
+        steps = [e for e in tr.events() if e.name in ("mixed_step", "decode_step")]
+        # a mixed step's chunks share its start: that pairs them
+        starts = {e.ts for e in steps if e.name == "mixed_step"}
+        assert {e.ts for e in tr.events()
+                if e.name.startswith("prefill_chunk[")} <= starts
+        ctx = [len(r.prompt) + k for r in reqs for k in range(r.max_new - 1)]
+        assert sum(e.args["decode_ctx_tokens"] for e in steps) == sum(ctx)
+        assert sum(e.args["decode_pages"] for e in steps) == \
+            sum(-(-(n + 1) // ps) for n in ctx)
 
 
 # ------------------------------------------------ Prometheus exposition
