@@ -13,11 +13,11 @@ One chip, in one process:
      tokens and 32 new tokens each, through chunked prefill, mixed steps and
      decode steps, and every request must finish with all its tokens;
   3. checks that every kernel cell the engine dispatched is a Pallas one;
-  4. runs one prefill chunk (``model.mixed_step``) and one decode step
-     (``model.decode_step``) with ``impl="pallas"``, in which every
-     transformer block, the LM head (the logits) and every kernel is also
-     computed with ``impl="jnp"`` on the same inputs, and bounds each
-     difference.
+  4. runs one packed mixed step of two prefill chunks (``model.mixed_step``)
+     and one decode step (``model.decode_step``) with ``impl="pallas"``, in
+     which every transformer block, the LM head (the logits) and every
+     kernel is also computed with ``impl="jnp"`` on the same inputs, and
+     bounds each difference.
 
 Four chips (``--four-chips``): a few training steps of the same model at
 sequence length 4096 on a 2x2 (data x model) mesh, set up by the training
@@ -52,14 +52,15 @@ N_SLOTS = 8
 CHUNK = 256  # prefill chunk = mixed-step token budget
 S_MAX = 1088  # PROMPT_MAX + MAX_NEW, rounded up to whole 16-row pages
 
-# Pallas vs jnp, on the inputs the Pallas run produces: one prefill chunk
-# (``model.mixed_step``) and one fused decode step (``model.decode_step``)
-# run with impl="pallas", and a function of that run is also computed with
-# impl="jnp" on the same arguments; the Pallas result is carried on. Run
-# free, the two implementations cannot be compared tightly: their float glue
-# (norms, softmax, residual adds) is compiled into two XLA programs that may
-# round differently, and 24 layers of 8-bit activations amplify one such
-# flip (13.5% of the logits' range on the prefill chunk, on a TPU v5e).
+# Pallas vs jnp, on the inputs the Pallas run produces: one packed mixed step
+# of two prefill chunks (``model.mixed_step``) and one fused decode step
+# (``model.decode_step``) run with impl="pallas", and a function of that run
+# is also computed with impl="jnp" on the same arguments; the Pallas result
+# is carried on. Run free, the two implementations cannot be compared
+# tightly: their float glue (norms, softmax, residual adds) is compiled into
+# two XLA programs that may round differently, and 24 layers of 8-bit
+# activations amplify one such flip (13.5% of the logits' range on the
+# prefill chunk, on a TPU v5e).
 # Paired this way, nothing builds up over layers.
 #
 # Per kernel (``kernels.ops``): the page movers copy bytes, so they must
@@ -189,20 +190,30 @@ def logits_check(params, cfg, policy) -> None:
 
     from repro.kernels import ops
     from repro.models import model as M
+    from repro.models.attention import PAD_POS
 
     B, W, ps = 2, CHUNK, 16
     nb = (W + ps) // ps  # pages for the chunk plus the decoded token
     rng = np.random.default_rng(SEED + 1)
-    toks = jnp.asarray(rng.integers(0, cfg.vocab, (B, W)), jnp.int32)
     nxt = jnp.asarray(rng.integers(0, cfg.vocab, (B, 1)), jnp.int32)
     bt = jnp.arange(1, B * nb + 1, dtype=jnp.int32).reshape(B, nb)
-    zeros = jnp.zeros((B,), jnp.int32)
     pos = jnp.full((B,), W, jnp.int32)
+    # the packed mixed step: both lanes' W-token chunks back to back (budget
+    # B * W), then one pad decode row per lane
+    toks = jnp.asarray(rng.integers(0, cfg.vocab, B * W + B), jnp.int32)
+    rows = dict(
+        pos=jnp.concatenate([jnp.tile(jnp.arange(W, dtype=jnp.int32), B),
+                             jnp.full((B,), PAD_POS, jnp.int32)]),
+        lanes=jnp.concatenate([jnp.repeat(jnp.arange(B, dtype=jnp.int32), W),
+                               jnp.arange(B, dtype=jnp.int32)]),
+        emit=jnp.arange(1, B + 1, dtype=jnp.int32) * W - 1,
+        starts=jnp.arange(B, dtype=jnp.int32) * W)
 
     def run():
         prefill = jax.jit(lambda p, t, c: M.mixed_step(
-            p, t, zeros, pos, c, cfg, policy, impl="pallas", block_tables=bt,
-            page_size=ps))
+            p, t, rows["pos"], rows["lanes"], c, cfg, policy,
+            emit=rows["emit"], starts=rows["starts"], impl="pallas",
+            block_tables=bt))
         decode = jax.jit(lambda p, t, c: M.decode_step(
             p, t, pos, c, cfg, policy, impl="pallas", block_tables=bt,
             fused_attn=True))

@@ -12,7 +12,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -23,6 +23,10 @@ from repro.core.policy import BF16, PrecisionPolicy
 from repro.kernels import ops
 
 BIG_NEG = -2.0e9
+
+#: write position of a packed step's pad rows: past every cache, so a dense
+#: write drops and a paged one lands in the scratch page
+PAD_POS = 2**30
 
 
 @dataclasses.dataclass(frozen=True)
@@ -285,20 +289,64 @@ def seq_insert(buf: jax.Array, new: jax.Array, pos: jax.Array, *,
     return buf.at[jnp.arange(B)[:, None], idx].set(new, mode="drop")
 
 
+class PackedRows(NamedTuple):
+    """Where the rows of a packed mixed step live (``model.mixed_step``).
+
+    Rows ``[0, budget)`` carry the step's prefill chunks, each contiguous;
+    rows ``[budget, T)`` are the decode rows, row ``budget + s`` slot
+    ``s``'s. ``lane`` (T,) is each row's slot and ``pos`` (T,) its write
+    position, ``PAD_POS`` for a pad. ``starts`` (P,) is the first row of
+    each prefill chunk, ascending, ``budget`` where the step has fewer."""
+
+    lane: jax.Array
+    pos: jax.Array
+    starts: jax.Array
+    budget: int
+
+
+def packed_insert(buf: jax.Array, new: jax.Array, rows: PackedRows, *,
+                  block_table: Optional[jax.Array] = None,
+                  impl: ops.Impl = "auto") -> jax.Array:
+    """Write row t of ``new`` (T, 1, ...) at position ``rows.pos[t]`` of
+    slot ``rows.lane[t]``. Dense ``buf`` (n_slots, S_max, ...): pads drop.
+    Paged: each row is a one-token write through a one-entry block table
+    ``[[page]]`` at its in-page offset, the page looked up here; a pad (or
+    a row past its table) gets the scratch page 0, so no real page sees
+    it."""
+    if block_table is None:
+        return buf.at[rows.lane, rows.pos].set(new[:, 0].astype(buf.dtype),
+                                               mode="drop")
+    ps, nb = buf.shape[1], block_table.shape[1]
+    blk = rows.pos // ps
+    page = jnp.where(blk < nb,
+                     block_table[rows.lane, jnp.minimum(blk, nb - 1)], 0)
+    return ops.paged_scatter(buf, new, rows.pos % ps, page[:, None],
+                             impl=impl)
+
+
 def cache_update(cache: dict, k: jax.Array, v: jax.Array, pos: jax.Array,
                  bits: Optional[int], *,
                  block_table: Optional[jax.Array] = None,
-                 impl: ops.Impl = "auto") -> dict:
-    """Insert new k/v (B, S_new, H, D) at ``pos`` (scalar or (B,))."""
+                 impl: ops.Impl = "auto",
+                 rows: Optional[PackedRows] = None) -> dict:
+    """Insert new k/v (B, S_new, H, D) at ``pos`` (scalar or (B,)); with
+    ``rows``, the T rows (T, 1, H, D) of a packed step where ``rows`` says
+    (``packed_insert``; ``pos`` unused)."""
     kq, ks = kv_quantize(k, bits)
     vq, vs = kv_quantize(v, bits)
     pg = dict(block_table=block_table, impl=impl)
+
+    def put(buf, new):
+        if rows is not None:
+            return packed_insert(buf, new, rows, **pg)
+        return seq_insert(buf, new, pos, **pg)
+
     out = dict(cache)
-    out["k"] = seq_insert(cache["k"], kq, pos, **pg)
-    out["v"] = seq_insert(cache["v"], vq, pos, **pg)
+    out["k"] = put(cache["k"], kq)
+    out["v"] = put(cache["v"], vq)
     if bits is not None:
-        out["k_s"] = seq_insert(cache["k_s"], ks, pos, **pg)
-        out["v_s"] = seq_insert(cache["v_s"], vs, pos, **pg)
+        out["k_s"] = put(cache["k_s"], ks)
+        out["v_s"] = put(cache["v_s"], vs)
     return out
 
 
@@ -356,6 +404,7 @@ def attn_apply(
     attend_cached: bool = False,
     block_table: Optional[jax.Array] = None,
     fused: bool = False,
+    packed: Optional[PackedRows] = None,
 ):
     """Returns (y, new_cache). Prefill/train: cache None -> flash path.
     Decode: cache given, S == new tokens (typically 1).
@@ -377,7 +426,11 @@ def attn_apply(
     paged-attention kernel (kernels/paged_attn.py): the block table is walked
     inside the kernel and quantized pages are dequantized in VMEM, so the
     gather-to-dense materialization below (``cache_read``) never runs. Other
-    shapes (chunked prefill, cross-attn, non-causal) fall back unchanged."""
+    shapes (chunked prefill, cross-attn, non-causal) fall back unchanged.
+
+    ``packed`` takes the rows of a packed mixed step, x (T, 1, d_model):
+    each row is written where ``packed`` says and attends through
+    :func:`packed_attend`."""
     B, S, _ = x.shape
     lp_qkv = policy.of("attn_qkv")
     lp_out = policy.of("attn_out")
@@ -397,6 +450,15 @@ def attn_apply(
         k, v = kv_override  # pre-computed encoder K/V (whisper cross-attn)
 
     bits = policy.kv_cache_bits
+    if packed is not None:
+        new_cache = cache_update(cache, k, v, None, bits,
+                                 block_table=block_table, impl=impl,
+                                 rows=packed)
+        y = packed_attend(q[:, 0], new_cache, packed, cfg, bits,
+                          block_table=block_table, impl=impl)
+        out = linear_apply(params["wo"], y.reshape(B, S, cfg.q_dim), lp_out,
+                           mode=mode, impl=impl)
+        return out, new_cache
     new_cache = cache
     prefill = (cache is not None and S > 1 and kv_override is None
                and not attend_cached)
@@ -432,27 +494,83 @@ def attn_apply(
                             impl="jnp" if mode == "train" else impl)
     else:
         # decode / cross-attn: q is short, keys long -> single-pass softmax
-        groups = cfg.n_heads // k.shape[2]
-        kk = jnp.repeat(k, groups, axis=2) if groups > 1 else k
-        vv = jnp.repeat(v, groups, axis=2) if groups > 1 else v
-        s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32), kk.astype(jnp.float32))
-        s = s / (cfg.head_dim**0.5)
-        k_idx = jnp.arange(k.shape[1])
+        valid = None
         if cache is not None:
             pos_b = jnp.broadcast_to(jnp.atleast_1d(cache_pos), (B,))
             qpos = pos_b[:, None] + jnp.arange(S)[None]  # (B, S)
-            valid = k_idx[None, None, :] <= qpos[:, :, None]  # (B, S, Sk)
-            if not causal:
+            if causal:
+                valid = _cached_mask(qpos, k.shape[1], cfg.window)
+            else:
+                k_idx = jnp.arange(k.shape[1])
                 valid = k_idx[None, None, :] <= (pos_b[:, None, None] + S - 1)
-            if cfg.window is not None:
-                valid &= (qpos[:, :, None] - k_idx[None, None, :]) < cfg.window
-            s = jnp.where(valid[:, None], s, BIG_NEG)
-        p = jax.nn.softmax(s, axis=-1)
-        y = jnp.einsum("bhqk,bkhd->bqhd", p, vv.astype(jnp.float32)).astype(x.dtype)
+                if cfg.window is not None:
+                    valid &= (qpos[:, :, None] - k_idx[None, None, :]) < cfg.window
+        y = _single_pass(q, k, v, valid, cfg).astype(x.dtype)
 
     y = y.reshape(B, S, cfg.q_dim)
     out = linear_apply(params["wo"], y, lp_out, mode=mode, impl=impl)
     return out, new_cache
+
+
+def _cached_mask(qpos: jax.Array, n_keys: int,
+                 window: Optional[int]) -> jax.Array:
+    """(B, S, n_keys) causal (and windowed) mask of queries at ``qpos``
+    (B, S) over a table's key rows."""
+    k_idx = jnp.arange(n_keys)
+    valid = k_idx[None, None, :] <= qpos[:, :, None]
+    if window is not None:
+        valid &= (qpos[:, :, None] - k_idx[None, None, :]) < window
+    return valid
+
+
+def _single_pass(q, k, v, valid, cfg: AttnCfg) -> jax.Array:
+    """Single-pass softmax attention of q (B, S, H, D) over whole key rows
+    k/v (B, Sk, Hkv, D), masked by ``valid`` (B, S, Sk) unless None; f32
+    out (B, S, H, D). Query head h reads KV head h // (H / Hkv): the query
+    heads are grouped onto their KV head, never the K/V repeated per query
+    head (on a decode batch that copy is most of the step)."""
+    B, S, H, D = q.shape
+    Hkv = k.shape[2]
+    qg = q.reshape(B, S, Hkv, H // Hkv, D).astype(jnp.float32)
+    s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k.astype(jnp.float32))
+    s = s / (cfg.head_dim**0.5)
+    if valid is not None:
+        s = jnp.where(valid[:, None, None], s, BIG_NEG)
+    p = jax.nn.softmax(s, axis=-1)
+    y = jnp.einsum("bhgqk,bkhd->bqhgd", p, v.astype(jnp.float32))
+    return y.reshape(B, S, H, v.shape[-1])
+
+
+def packed_attend(q: jax.Array, cache: dict, rows: PackedRows, cfg: AttnCfg,
+                  bits: Optional[int], *,
+                  block_table: Optional[jax.Array] = None,
+                  impl: ops.Impl = "auto") -> jax.Array:
+    """Attention of a packed step's queries q (T, H, D), its rows already
+    written to ``cache``; returns (T, H, D) in q's dtype.
+
+    Decode rows attend as :func:`attn_apply` decodes a batch (S = 1, each
+    over its own slot's table, same mask). Prefill rows are gathered into
+    one (P, budget) query block per chunk, each block attending over its
+    chunk's slot's table only, and gathered back; block rows past their
+    chunk are computed and dropped."""
+    W = rows.budget
+    T = q.shape[0]
+    k, v = cache_read(cache, bits, block_table=block_table, impl=impl)
+    y_dec = _single_pass(q[W:, None], k, v,
+                         _cached_mask(rows.pos[W:, None], k.shape[1], cfg.window),
+                         cfg)[:, 0]
+    idx = jnp.minimum(rows.starts[:, None] + jnp.arange(W)[None], T - 1)
+    lanes = rows.lane[idx[:, 0]]
+    if block_table is not None:
+        k, v = cache_read(cache, bits, block_table=block_table[lanes], impl=impl)
+    else:
+        k, v = cache_read(jax.tree.map(lambda a: a[lanes], cache), bits)
+    y_blk = _single_pass(q[idx], k, v,
+                         _cached_mask(rows.pos[idx], k.shape[1], cfg.window), cfg)
+    t = jnp.arange(W)
+    chunk = jnp.sum(t[:, None] >= rows.starts[None, 1:], axis=1)  # (W,)
+    y_pre = y_blk[chunk, t - rows.starts[chunk]]
+    return jnp.concatenate([y_pre, y_dec]).astype(q.dtype)
 
 
 # ---------------------------------------------------------------- MLA block

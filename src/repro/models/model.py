@@ -27,6 +27,7 @@ from repro.models import ssm
 from repro.models.attention import (
     AttnCfg,
     MLACfg,
+    PackedRows,
     attn_apply,
     attn_init,
     cache_init,
@@ -178,8 +179,10 @@ def _block_init(key, cfg: ArchConfig, policy, mode, dtype, *, kind: str) -> dict
 
 def _block_apply(params, x, pos, cfg: ArchConfig, policy, *, kind, mode, impl,
                  cache=None, cache_pos=None, cross_kv=None, causal=True,
-                 attend_cached=False, block_tables=None, fused_attn=False):
-    """Returns (x_out, new_cache, aux)."""
+                 attend_cached=False, block_tables=None, fused_attn=False,
+                 packed=None):
+    """Returns (x_out, new_cache, aux). ``packed``: the rows of a packed
+    mixed step (attention.PackedRows), for GQA blocks."""
     _, nfn = _norm_fns(cfg)
     aux = jnp.zeros((), jnp.float32)
     if kind in ("dense", "moe", "mla_dense", "mla_moe", "enc", "dec"):
@@ -198,7 +201,7 @@ def _block_apply(params, x, pos, cfg: ArchConfig, policy, *, kind, mode, impl,
                                    cache=sc, cache_pos=cache_pos,
                                    attend_cached=attend_cached,
                                    block_table=block_tables,
-                                   fused=fused_attn)
+                                   fused=fused_attn, packed=packed)
             new_cache = cache if cache is None else dict(cache, self=sc_new)
         x = x + a
         if kind == "dec":
@@ -324,7 +327,7 @@ def _run_stack(params, x, pos, cfg: ArchConfig, policy, *, mode, impl,
                caches=None, cache_pos=None, cross_kv=None, causal=True,
                remat: bool = True, remat_policy: str = "full",
                attend_cached: bool = False, block_tables=None,
-               fused_attn: bool = False):
+               fused_attn: bool = False, packed=None):
     """Scan the grouped block stacks. caches: list matching groups (stacked
     leading dim) or None. Returns (x, new_caches, aux_sum).
 
@@ -350,7 +353,7 @@ def _run_stack(params, x, pos, cfg: ArchConfig, policy, *, mode, impl,
                 bp, h, pos, cfg, policy, kind=kind, mode=mode, impl=impl,
                 cache=bc, cache_pos=cache_pos, cross_kv=ckv, causal=causal,
                 attend_cached=attend_cached, block_tables=block_tables,
-                fused_attn=fused_attn)
+                fused_attn=fused_attn, packed=packed)
             return (h2.astype(h.dtype), auxc + aux), nc
 
         body_fn = (_remat_wrap(body, remat_policy)
@@ -821,19 +824,89 @@ def prefill_into_pages(params: dict, tokens: jax.Array, block_row: jax.Array,
     return logits, new_caches
 
 
+#: Families whose mixed steps run packed (:func:`mixed_step`). ``moe`` and
+#: ``mla_moe`` stay on :func:`mixed_step_padded`: ``moe_apply`` sizes expert
+#: capacity from the step's token count, so packing would change their
+#: numbers, and MLA's absorbed attention has no packed path yet.
+PACKED_FAMILIES = ("dense",)
+
+
 def mixed_step(params: dict, tokens: jax.Array, pos: jax.Array,
-               n_real: jax.Array, caches: list, cfg: ArchConfig,
-               policy: PrecisionPolicy, *,
-               impl: ops.Impl = "auto",
-               block_tables: Optional[jax.Array] = None,
-               page_size: Optional[int] = None):
-    """One continuous-batching step: every lane of the (B, W) token batch is
-    either a DECODE lane (``n_real[b] == 1``: its next single token), a
-    PREFILL lane (``n_real[b]`` up to W: a right-padded chunk of its prompt),
-    or idle (``n_real[b] == 0``). All lanes lower through ONE forward — a
-    long prompt no longer monopolizes the device between decode steps
-    (Sarathi-style chunked piggybacking), it rides the decode batch W
-    prompt tokens at a time.
+               lanes: jax.Array, caches: list, cfg: ArchConfig,
+               policy: PrecisionPolicy, *, emit: jax.Array,
+               starts: jax.Array, impl: ops.Impl = "auto",
+               block_tables: Optional[jax.Array] = None):
+    """One continuous-batching step over a PACKED token batch: only rows
+    that can carry a token are computed, ``T = budget + n_slots`` of them,
+    where the padded layout (:func:`mixed_step_padded`) computes
+    ``n_slots x budget``. A long prompt rides the decode batch a chunk at
+    a time (Sarathi-style chunked piggybacking) without monopolizing the
+    device between decode steps.
+
+    Layout (``tokens``, ``pos``, ``lanes`` all (T,) int32):
+
+    - rows ``[0, budget)``: this step's prefill chunks, each contiguous, in
+      allotment order, right-padded; ``starts`` (P,) holds each chunk's
+      first row, ascending, and ``budget`` for blocks the step leaves
+      empty (P is static: the most chunks a step carries);
+    - rows ``[budget, T)``: the decode rows, row ``budget + s`` slot s's;
+    - ``lanes[t]`` is row t's slot, ``pos[t]`` its cache write position
+      (and RoPE position). A pad row — the unused prefill tail, the decode
+      row of an idle or still-prefilling slot — has ``pos`` ``PAD_POS``:
+      a dense write drops, a paged one lands in the scratch page 0, so pad
+      rows never touch a real cache row and nothing needs scrubbing.
+    - ``emit`` (n_slots,): the row whose logits slot s returns (its final
+      chunk's last row, or its decode row).
+
+    Row-wise work (embed, every quantized linear, norms, RoPE, SwiGLU) runs
+    on the T rows; activation quantisation clips at the static PACT beta,
+    so rows are independent and packing changes no row's numbers.
+    Attention (``attention.packed_attend``): decode rows attend exactly as
+    :func:`decode_step` with ``fused_attn=False`` does; each prefill chunk
+    attends as one (budget,) query block over its slot's table only — the
+    cached-attention math of :func:`prefill_chunk`. So token streams stay
+    bit-equal to the serialized engine's with ``fused_attn=False`` (greedy
+    streams also equal its fused decode's, as in the padded step).
+    ``block_tables`` (n_slots, n_blocks) selects the paged pool layout
+    (page size: the pool's axis 2).
+
+    Only :data:`PACKED_FAMILIES` run packed. Returns (logits (n_slots, 1,
+    V), new_caches); rows of idle slots return garbage the caller discards.
+    """
+    if cfg.family not in PACKED_FAMILIES:
+        raise NotImplementedError(
+            f"packed mixed steps unsupported for family {cfg.family!r} "
+            f"(packed: {PACKED_FAMILIES}); use mixed_step_padded")
+    _, nfn = _norm_fns(cfg)
+    mode = "serve"
+    T = tokens.shape[0]
+    rows = PackedRows(lane=jnp.asarray(lanes, jnp.int32),
+                      pos=jnp.asarray(pos, jnp.int32),
+                      starts=jnp.asarray(starts, jnp.int32),
+                      budget=T - emit.shape[0])
+    x = embed_apply(params["embed"], tokens[:, None]).astype(jnp.bfloat16)
+    x, new_caches, _ = _run_stack(params, x, rows.pos[:, None], cfg, policy,
+                                  mode=mode, impl=impl, caches=caches,
+                                  remat=False, block_tables=block_tables,
+                                  packed=rows)
+    x_last = nfn(params["final_norm"], x[emit])
+    logits = linear_apply(params["head"], x_last, policy.of("head"), mode=mode,
+                          impl=impl)
+    return logits, new_caches
+
+
+def mixed_step_padded(params: dict, tokens: jax.Array, pos: jax.Array,
+                      n_real: jax.Array, caches: list, cfg: ArchConfig,
+                      policy: PrecisionPolicy, *,
+                      impl: ops.Impl = "auto",
+                      block_tables: Optional[jax.Array] = None,
+                      page_size: Optional[int] = None):
+    """The PADDED mixed step, for the chunkable families outside
+    :data:`PACKED_FAMILIES` (``moe``, ``mla_moe``): every lane of the (B, W)
+    token batch is either a DECODE lane (``n_real[b] == 1``: its next
+    single token), a PREFILL lane (``n_real[b]`` up to W: a right-padded
+    chunk of its prompt), or idle (``n_real[b] == 0``), and all B x W rows
+    are computed.
 
     The whole batch attends through the cache (``attend_cached``, the same
     branch :func:`prefill_chunk` uses), so a decode lane here is numerically
@@ -928,7 +1001,7 @@ def spec_verify_step(params: dict, tokens: jax.Array, pos: jax.Array,
     window in ONE jitted call. Lane b of ``tokens`` (B, W = k+1) is
     ``[last_emitted, draft_0, .., draft_{k-1}]`` for a speculating lane
     (``n_real[b] == W``), a plain right-padded 1-token decode lane
-    (``n_real[b] == 1``), or idle (0). The forward is :func:`mixed_step`'s
+    (``n_real[b] == 1``), or idle (0). The forward is :func:`mixed_step_padded`'s
     (``attend_cached`` through the shared cache, per-lane pad scrub after),
     with two differences:
 
@@ -978,7 +1051,7 @@ def spec_verify_step(params: dict, tokens: jax.Array, pos: jax.Array,
         jnp.repeat(top_ps, W), jnp.repeat(seeds, W),
         ctr.reshape(-1)).reshape(B, W)
 
-    # per-lane pad scrub, verbatim from mixed_step: no stale K/V beyond a
+    # per-lane pad scrub, verbatim from mixed_step_padded: no stale K/V beyond a
     # lane's real rows (rejected rows are rolled back by truncate, which
     # scrubs separately — this handles pad lanes and idle lanes)
     row_idx = pos[:, None] + jnp.arange(W, dtype=jnp.int32)[None]   # (B, W)

@@ -46,8 +46,10 @@ page-pool health, and straggler counts.
 batching — the engine-loop restructuring the serialized mode's step
 anatomy cannot express:
 
-  * **Mixed steps** (``models.model.mixed_step``): prefill chunks ride the
-    decode batch under a per-step token budget (``mixed_budget``), so a
+  * **Mixed steps** (``models.model.mixed_step``, packed: only the
+    ``mixed_budget + n_slots`` rows that can carry a token are computed):
+    prefill chunks ride the decode batch under a per-step token budget
+    (``mixed_budget``, at most ``MIXED_MAX_CHUNKS`` chunks), so a
     long prompt no longer monopolizes the device between decode steps —
     in-flight streams keep their inter-token cadence while the newcomer
     prefills ``Scheduler.allot``-sized chunks per step.
@@ -63,9 +65,10 @@ anatomy cannot express:
     retire path drops them by request identity.
 
 Token streams are bit-identical to the serialized engine on all three
-cache backends: mixed-step lanes are row-independent and pad-scrubbed
-(see ``mixed_step``), and the counter-based sampler makes each stream a
-pure function of (params, prompt, sampling params).
+cache backends: mixed-step rows are independent and pad rows never write
+(packed, ``mixed_step``) or are scrubbed (padded, ``mixed_step_padded``),
+and the counter-based sampler makes each stream a pure function of
+(params, prompt, sampling params).
 """
 
 from __future__ import annotations
@@ -81,6 +84,7 @@ import numpy as np
 from repro.core.policy import PrecisionPolicy
 from repro.kernels import dispatch
 from repro.models import model as M
+from repro.models.attention import PAD_POS
 from repro.models.model import ArchConfig
 from repro.serve.api import (
     ACTIVE,
@@ -101,6 +105,10 @@ from repro.serve.scheduler import Scheduler, make_scheduler
 from repro.serve.spec import DraftPolicy, make_spec
 from repro.serve.stats import LatencyHistogram
 from repro.serve.trace import ENGINE_TRACK, Tracer, slot_track
+
+#: most prefill chunks one packed mixed step carries: its static number of
+#: prefill query blocks (``model.mixed_step``'s P)
+MIXED_MAX_CHUNKS = 2
 
 
 class StepMonitor:
@@ -280,19 +288,30 @@ class ServeEngine:
         self._ring = SnapshotRing(self.inflight_depth + 2)
         self._progress = 0  # admissions+dispatches+retires+releases (drain)
         self._mixed_steps = 0
+        #: mixed steps run packed (model.mixed_step) for PACKED_FAMILIES;
+        #: moe / mla_moe keep the padded (n_slots, budget) batch
+        self._packed = packed = cfg.family in M.PACKED_FAMILIES
         if self.mixed:
             ps = self.cache.page_size if self.cache.paged else None
+            W = self.mixed_budget
 
-            def mixed_and_sample(p, host_toks, chain, use_chain, pos, n_real,
+            def mixed_and_sample(p, host_toks, chain, use_chain, pos, rows,
                                  caches, samp, bt=None):
                 # decode lanes take their input from the DEVICE chain (the
                 # previous step's sampled output); prefill/idle lanes keep
                 # the host-provided rows
-                toks = host_toks.at[:, 0].set(
-                    jnp.where(use_chain, chain, host_toks[:, 0]))
-                logits, new_caches = M.mixed_step(
-                    p, toks, pos, n_real, caches, cfg, policy, impl=impl,
-                    block_tables=bt, page_size=ps)
+                if packed:
+                    lanes, emit, starts = rows
+                    toks = host_toks.at[W:].set(jnp.where(use_chain, chain, 0))
+                    logits, new_caches = M.mixed_step(
+                        p, toks, pos, lanes, caches, cfg, policy, emit=emit,
+                        starts=starts, impl=impl, block_tables=bt)
+                else:
+                    toks = host_toks.at[:, 0].set(
+                        jnp.where(use_chain, chain, host_toks[:, 0]))
+                    logits, new_caches = M.mixed_step_padded(
+                        p, toks, pos, rows, caches, cfg, policy, impl=impl,
+                        block_tables=bt, page_size=ps)
                 nxt = M.sample_tokens(logits[:, 0], *samp)
                 return nxt, new_caches
 
@@ -305,17 +324,17 @@ class ServeEngine:
                 return nxt, new_caches
 
             if self.cache.paged:
-                def serve_mixed_step(p, toks, chain, uc, pos, nr, bt, caches,
+                def serve_mixed_step(p, toks, chain, uc, pos, rows, bt, caches,
                                      samp):
-                    return mixed_and_sample(p, toks, chain, uc, pos, nr,
+                    return mixed_and_sample(p, toks, chain, uc, pos, rows,
                                             caches, samp, bt=bt)
 
                 def serve_decode_step(p, chain, pos, bt, caches, samp):
                     return chain_and_sample(p, chain, pos, caches, samp, bt=bt)
             else:
-                def serve_mixed_step(p, toks, chain, uc, pos, nr, caches,
+                def serve_mixed_step(p, toks, chain, uc, pos, rows, caches,
                                      samp):
-                    return mixed_and_sample(p, toks, chain, uc, pos, nr,
+                    return mixed_and_sample(p, toks, chain, uc, pos, rows,
                                             caches, samp)
 
                 def serve_decode_step(p, chain, pos, caches, samp):
@@ -756,23 +775,71 @@ class ServeEngine:
                 self._ring.take("seeds", self._seeds),
                 self._ring.take("counters", self._counters))
 
+    def _packed_rows(self, chunks, decode_lanes):
+        """Host half of a packed mixed step (``model.mixed_step``): rows
+        ``[0, budget)`` hold the chunks back to back, row ``budget + s`` is
+        slot s's decode row; every other row is a pad at ``PAD_POS``.
+        Returns (tokens, use_chain, pos, (lanes, emit, starts))."""
+        W, n = self.mixed_budget, self.n_slots
+        toks = np.zeros(W + n, np.int32)
+        pos = np.full(W + n, PAD_POS, np.int32)
+        lanes = np.concatenate([np.zeros(W, np.int32),
+                                np.arange(n, dtype=np.int32)])
+        emit = np.arange(W, W + n, dtype=np.int32)
+        starts = np.full(MIXED_MAX_CHUNKS, W, np.int32)
+        use_chain = np.zeros(n, bool)
+        row = 0
+        for i, (s, chunk) in enumerate(chunks):
+            k = len(chunk)
+            toks[row:row + k] = chunk
+            pos[row:row + k] = self.cache.pos[s] + np.arange(k)
+            lanes[row:row + k] = s
+            starts[i] = row
+            emit[s] = row + k - 1
+            row += k
+        for s in decode_lanes:
+            pos[W + s] = self.cache.pos[s]
+            use_chain[s] = True
+        return toks, use_chain, pos, (lanes, emit, starts)
+
+    def _padded_rows(self, chunks, decode_lanes):
+        """Host half of a padded mixed step (``model.mixed_step_padded``):
+        lane s carries slot s's chunk or decode token. Returns (tokens,
+        use_chain, pos, n_real)."""
+        host_toks = np.zeros((self.n_slots, self.mixed_budget), np.int32)
+        n_real = np.zeros(self.n_slots, np.int32)
+        use_chain = np.zeros(self.n_slots, bool)
+        for s, chunk in chunks:
+            host_toks[s, :len(chunk)] = chunk
+            n_real[s] = len(chunk)
+        for s in decode_lanes:
+            n_real[s] = 1
+            use_chain[s] = True
+        return host_toks, use_chain, self.cache.pos, n_real
+
     def _dispatch(self) -> bool:
         """Issue ONE step without waiting for its result (continuous mode).
 
         Decode lanes feed on the device-side ``_chain`` (the previous
         dispatch's sampled output — no host readback); prefill lanes carry
-        their scheduler-allotted chunk of prompt tokens. Bookkeeping that
-        the host mutates afterwards crosses the boundary via the snapshot
-        ring. PRNG counters and per-slot budgets advance SPECULATIVELY here
-        — the retire side only materializes tokens. Returns False when no
-        lane had work to dispatch."""
+        their scheduler-allotted chunk of prompt tokens. A step with chunks
+        is a mixed step: PACKED (``model.mixed_step``: budget + n_slots
+        rows, at most ``MIXED_MAX_CHUNKS`` chunks, the cap passed to
+        ``Scheduler.allot``) for ``model.PACKED_FAMILIES``, PADDED
+        (``model.mixed_step_padded``: n_slots x budget rows, no cap) for
+        moe / mla_moe. Bookkeeping that the host mutates afterwards crosses
+        the boundary via the snapshot ring. PRNG counters and per-slot
+        budgets advance SPECULATIVELY here — the retire side only
+        materializes tokens. Returns False when no lane had work to
+        dispatch."""
         decode_lanes = [
             s for s, r in enumerate(self.slot_req)
             if r is not None and s not in self._prefilling
             and self._spec_remaining[s] > 0]
-        allot = (self.scheduler.allot(list(self._prefilling.values()),
-                                      self.mixed_budget)
-                 if self._prefilling else [])
+        cursors = list(self._prefilling.values())
+        cap = MIXED_MAX_CHUNKS if self._packed else None
+        allot = (self.scheduler.allot(cursors, self.mixed_budget, max_chunks=cap)
+                 if cursors else [])
         if not decode_lanes and not allot:
             return False
         sp = None
@@ -787,17 +854,17 @@ class ServeEngine:
             sp = self.trace.scope(
                 "mixed_step" if allot else "decode_step", cat="engine",
                 step=self._decode_steps, decode_ctx_tokens=sum(ctx), **pages)
+            # cursors with work the chunk cap held back this step
+            deferred = (len(self.scheduler.allot(cursors, self.mixed_budget))
+                        - len(allot)) if allot else 0
         # a step's prefill_chunk spans share its start (readers pair them)
         t0 = time.perf_counter() if sp is None else sp.t0_ns * 1e-9
         #: (slot, request, emits): emits=False for non-final prefill chunks
         lanes: list[tuple[int, Request, bool]] = []
         if allot:
-            # mixed step: prefill chunks ride the decode batch, width =
-            # the token budget (static; one trace per backend)
-            W = self.mixed_budget
-            host_toks = np.zeros((self.n_slots, W), np.int32)
-            n_real = np.zeros(self.n_slots, np.int32)
-            use_chain = np.zeros(self.n_slots, bool)
+            # mixed step: prefill chunks ride the decode batch (static
+            # shapes; one trace per backend)
+            chunks: list[tuple[int, np.ndarray]] = []
             writes: list[tuple[int, int]] = []
             commits: list[tuple[int, Request]] = []
             chunkinfo: list[tuple[int, int, int, int, int]] = []
@@ -805,8 +872,7 @@ class ServeEngine:
                 s = cur.slot
                 off = cur.off
                 chunk = cur.take(n)
-                host_toks[s, :len(chunk)] = chunk
-                n_real[s] = len(chunk)
+                chunks.append((s, chunk))
                 self.cache.prepare(s, len(chunk))  # paged: draw pages
                 writes.append((s, len(chunk)))
                 # the final chunk's lane emits the request's FIRST token
@@ -816,18 +882,19 @@ class ServeEngine:
                 if cur.done:
                     commits.append((s, cur.req))
             for s in decode_lanes:
-                n_real[s] = 1
-                use_chain[s] = True
                 self.cache.prepare(s, 1)
                 writes.append((s, 1))
                 lanes.append((s, self.slot_req[s], True))
-            # snapshots AFTER every prepare (prepare mutates block tables),
-            # BEFORE the speculative counter bump below
+            # rows and snapshots AFTER every prepare (prepare mutates block
+            # tables), BEFORE the advances and the speculative counter bump
+            toks, use_chain, pos, rows = (
+                self._packed_rows if self._packed else self._padded_rows)(
+                    chunks, decode_lanes)
             samp = self._samp_snapshot()
-            args = (self.params, jnp.asarray(host_toks), self._chain,
+            args = (self.params, jnp.asarray(toks), self._chain,
                     self._ring.take("use_chain", use_chain),
-                    self._ring.take("pos", self.cache.pos),
-                    self._ring.take("n_real", n_real))
+                    self._ring.take("mixed_pos", pos),
+                    jax.tree.map(jnp.asarray, rows))
             if self.cache.paged:
                 nxt, self.cache.caches = self._mixed(
                     *args, self._ring.take("bt", self.cache.block_tables),
@@ -892,10 +959,14 @@ class ServeEngine:
             # the engine-pipeline view of this dispatch: budget split,
             # in-flight depth, and the step's cache-counter deltas (pages
             # drawn / COW copies / evictions attributed to THIS step)
+            # a mixed step also records the rows it computed and the
+            # cursors the chunk cap deferred
+            rows_args = ({"rows": int(toks.size), "deferred_chunks": deferred}
+                         if allot else {})
             sp.close(decode_lanes=len(decode_lanes), prefill_lanes=len(allot),
                      prefill_tokens=int(sum(n for _, n in allot)),
                      budget=self.mixed_budget, inflight=len(self._tickets),
-                     **self._cache_deltas())
+                     **rows_args, **self._cache_deltas())
             self.trace.counter("queue_depth", self.scheduler.pending(),
                                ts=now)
             self.trace.counter("inflight", len(self._tickets), ts=now)

@@ -89,7 +89,8 @@ class Scheduler:
 
     # --- mixed-step budget allotment ---------------------------------------
 
-    def allot(self, cursors: Sequence, budget: int) -> list[tuple]:
+    def allot(self, cursors: Sequence, budget: int,
+              max_chunks: Optional[int] = None) -> list[tuple]:
         """Split a mixed step's prefill-token budget across the in-flight
         prompt cursors (``serve.prefill.PrefillCursor``). Returns
         ``[(cursor, n_tokens), ...]`` with ``sum(n) <= budget`` and every
@@ -98,11 +99,14 @@ class Scheduler:
         prompt's chunks stay consecutive and TTFT is FIFO-fair. A lane
         carries at most one chunk per step (the mixed step has one row
         span per lane), so a cursor's allotment is also capped by the
-        budget even when it is the only one."""
+        budget even when it is the only one. ``max_chunks`` caps how many
+        cursors one step serves (a packed step has that many query blocks);
+        a cursor the cap holds back keeps its place in that order."""
         take: list[tuple] = []
         budget = int(budget)
         for cur in sorted(cursors, key=self._allot_key):
-            if budget <= 0:
+            if budget <= 0 or (max_chunks is not None
+                               and len(take) >= max_chunks):
                 break
             n = min(cur.remaining, budget)
             if n >= 1:

@@ -3,12 +3,15 @@ BIT-IDENTICAL to the serialized engine on every cache backend (greedy and
 seeded-stochastic), the ahead-of-time dispatch pipeline must respect its
 in-flight bound, mixed-step churn (admissions, cancellations, stop
 sequences interleaved with in-flight decode) must conserve the page pool
-and never perturb a survivor's stream, drain() must yield (and eventually
-raise) instead of busy-spinning on queue-only work, and the supporting
+and never perturb a survivor's stream, one packed mixed step must equal
+each of its lanes run alone (moe keeps the padded step), drain() must
+yield (and eventually raise) instead of busy-spinning on queue-only work,
+and the supporting
 pieces — LatencyHistogram, SnapshotRing, PrefillCursor, Scheduler.allot —
 hold their unit contracts."""
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -17,6 +20,7 @@ from hypothesis import strategies as st
 from repro import configs
 from repro.core.policy import get_policy
 from repro.models import model as M
+from repro.models.attention import PAD_POS
 from repro.serve import (
     LatencyHistogram,
     PrefillCursor,
@@ -25,8 +29,10 @@ from repro.serve import (
     Scheduler,
     ServeEngine,
     SnapshotRing,
+    Tracer,
     make_scheduler,
 )
+from repro.serve.engine import MIXED_MAX_CHUNKS
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -272,6 +278,167 @@ def test_drain_completes_normally_after_transient_queueing(params):
     assert all(len(v) >= 4 for v in out.values())
 
 
+# ------------------------------------------------------ the packed step
+
+#: one packed step at budget 8 over 7 slots: (slot, tokens already cached,
+#: role). Slot 0 carries the final chunk of its prompt and slot 1 a
+#: non-final one, both at non-zero offsets; slots 2-4 decode; slot 5 is
+#: mid-prompt but not allotted this step; slot 6 is idle.
+_PACKED_SLOTS = [(0, 5, "final"), (1, 4, "chunk"), (2, 6, "decode"),
+                 (3, 9, "decode"), (4, 3, "decode"), (5, 4, "waiting"),
+                 (6, 0, "idle")]
+_PACKED_CHUNKS = {0: 3, 1: 4}  # slot -> tokens of its chunk this step
+_PACKED_BUDGET, _PACKED_S_MAX, _PACKED_PS = 8, 16, 4
+_PACKED_PARAMS: dict = {}
+
+
+def _packed_params(arch):
+    cfg = configs.reduced(configs.get_arch(arch))
+    if arch not in _PACKED_PARAMS:
+        _PACKED_PARAMS[arch] = M.init_params(jax.random.key(4), cfg, POLICY,
+                                             mode="serve")
+    return cfg, _PACKED_PARAMS[arch]
+
+
+def _packed_case(cfg, params, seed):
+    """A dense cache filled by real prefills, the step's packed host
+    arrays (laid out as the engine lays them) and each working slot's
+    tokens of the step."""
+    rng = np.random.RandomState(seed)
+    n, W = len(_PACKED_SLOTS), _PACKED_BUDGET
+    caches = M.init_cache(cfg, POLICY, n, _PACKED_S_MAX)
+    for s, cached, _ in _PACKED_SLOTS:
+        if cached:
+            prompt = jnp.asarray(rng.randint(1, cfg.vocab, (1, cached)), jnp.int32)
+            _, caches = M.prefill_into_slot(params, prompt, jnp.int32(s),
+                                            jnp.int32(0), caches, cfg, POLICY,
+                                            head=False, impl="jnp")
+    toks = np.zeros(W + n, np.int32)
+    pos = np.full(W + n, PAD_POS, np.int32)
+    lanes = np.concatenate([np.zeros(W, np.int32), np.arange(n, dtype=np.int32)])
+    emit = np.arange(W, W + n, dtype=np.int32)
+    starts = np.full(MIXED_MAX_CHUNKS, W, np.int32)
+    step_toks = {}
+    row = 0
+    for i, (s, k) in enumerate(_PACKED_CHUNKS.items()):
+        step_toks[s] = rng.randint(1, cfg.vocab, k).astype(np.int32)
+        toks[row:row + k] = step_toks[s]
+        pos[row:row + k] = _PACKED_SLOTS[s][1] + np.arange(k)
+        lanes[row:row + k] = s
+        starts[i], emit[s] = row, row + k - 1
+        row += k
+    for s, cached, role in _PACKED_SLOTS:
+        if role == "decode":
+            step_toks[s] = rng.randint(1, cfg.vocab, 1).astype(np.int32)
+            toks[W + s], pos[W + s] = step_toks[s][0], cached
+    return caches, (toks, pos, lanes, emit, starts), step_toks
+
+
+def _lane_reference(cfg, params, caches, step_toks):
+    """Each working slot's step run on its cache row alone: prefill_chunk
+    for a chunk, decode_step (unfused) for a decode token. Returns the
+    emitting slots' logits (1, V) and the dense cache the steps leave."""
+    logits, expect = {}, caches
+    for s, cached, role in _PACKED_SLOTS:
+        if s not in step_toks:
+            continue
+        row = jax.tree.map(lambda a: a[:, s:s + 1], caches)
+        t, p = jnp.asarray(step_toks[s][None]), jnp.int32([cached])
+        if role == "decode":
+            lg, row = M.decode_step(params, t, p, row, cfg, POLICY, impl="jnp")
+        else:
+            lg, row = M.prefill_chunk(params, t, p, row, cfg, POLICY,
+                                      head=role == "final", impl="jnp")
+        if lg is not None:
+            logits[s] = np.asarray(lg[:, -1])
+        expect = jax.tree.map(lambda a, r: a.at[:, s:s + 1].set(r), expect, row)
+    return logits, expect
+
+
+def _as_pages(caches, bt, n_pages):
+    """The dense cache laid into a zeroed page pool through ``bt``."""
+    n, nb = bt.shape
+
+    def lay(a):
+        pool = jnp.zeros((a.shape[0], n_pages, _PACKED_PS) + a.shape[3:], a.dtype)
+        rows = a.reshape(a.shape[0], n * nb, _PACKED_PS, *a.shape[3:])
+        return pool.at[:, bt.reshape(-1)].set(rows)
+
+    return jax.tree.map(lay, caches)
+
+
+@pytest.mark.parametrize("layout", ["dense", "paged"])
+@pytest.mark.parametrize("arch", ["internlm2-1.8b", "h2o-danube-1.8b"])
+def test_packed_mixed_step_matches_each_lane_alone(arch, layout):
+    """One packed mixed step holding two prefill chunks at non-zero
+    offsets (one final), three decode lanes, a still-prefilling slot and an
+    idle slot: every emitting slot's logits equal ``prefill_chunk`` /
+    ``decode_step`` run on that lane alone, the cache holds exactly what
+    those runs write, and — paged — no page outside the working lanes'
+    written rows changes except the scratch page 0."""
+    cfg, params = _packed_params(arch)
+    caches, (toks, pos, lanes, emit, starts), step_toks = _packed_case(
+        cfg, params, seed=1)
+    want_logits, want_cache = _lane_reference(cfg, params, caches, step_toks)
+    n = len(_PACKED_SLOTS)
+    bt = None
+    if layout == "paged":
+        nb = _PACKED_S_MAX // _PACKED_PS
+        n_pages = 1 + n * nb
+        bt = jnp.asarray(np.random.RandomState(2).permutation(
+            np.arange(1, n_pages)).reshape(n, nb), jnp.int32)
+        caches = _as_pages(caches, bt, n_pages)
+    logits, got = jax.jit(lambda c: M.mixed_step(
+        params, jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(lanes), c,
+        cfg, POLICY, emit=jnp.asarray(emit), starts=jnp.asarray(starts),
+        impl="jnp", block_tables=bt))(caches)
+    assert logits.shape == (n, 1, cfg.vocab_padded)
+    for s, lg in want_logits.items():
+        np.testing.assert_array_equal(np.asarray(logits[s]), lg, err_msg=f"slot {s}")
+    assert set(want_logits) == {0, 2, 3, 4}
+    if layout == "paged":
+        # the pages holding the rows the working lanes wrote, and page 0
+        written = {int(bt[s, p // _PACKED_PS]) for s, cached, _ in _PACKED_SLOTS
+                   if s in step_toks
+                   for p in range(cached, cached + len(step_toks[s]))}
+        for a, b in zip(jax.tree.leaves(caches), jax.tree.leaves(got)):
+            diff = np.asarray(a != b).reshape(a.shape[0], a.shape[1], -1)
+            changed = set(np.nonzero(diff.any(axis=(0, 2)))[0].tolist())
+            assert changed <= written | {0}, changed - written
+        got = jax.tree.map(lambda a: a[:, bt].reshape(
+            a.shape[0], n, _PACKED_S_MAX, *a.shape[3:]), got)
+    for a, b in zip(jax.tree.leaves(want_cache), jax.tree.leaves(got)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_packed_step_is_dense_only_and_moe_stays_padded(params):
+    """``moe`` (and ``mla_moe``) keep the padded mixed step: the packed one
+    refuses them, and a moe engine's mixed steps compute n_slots x budget
+    rows while its tokens stay equal to the serialized engine's; a dense
+    engine's compute budget + n_slots."""
+    moe = configs.reduced(configs.get_arch("granite-moe-1b-a400m"))
+    assert moe.family not in M.PACKED_FAMILIES
+    assert "mla_moe" not in M.PACKED_FAMILIES
+    with pytest.raises(NotImplementedError, match="mixed_step_padded"):
+        M.mixed_step(None, jnp.zeros(6, jnp.int32), None, None, None, moe,
+                     POLICY, emit=jnp.zeros(2, jnp.int32), starts=None)
+    mparams = M.init_params(jax.random.key(5), moe, POLICY, mode="serve")
+    for cfg, p in ((moe, mparams), (TINY, params)):
+        def mk():
+            return [Request(rid=i, prompt=r.prompt.copy(), max_new=r.max_new)
+                    for i, r in enumerate(_requests((3, 9, 6)))]
+
+        tr = Tracer()
+        kw = dict(n_slots=2, s_max=48, impl="jnp", cache="paged", page_size=8,
+                  n_pages=40)
+        want = ServeEngine(p, cfg, POLICY, **kw).run(mk())
+        got = ServeEngine(p, cfg, POLICY, mixed=True, mixed_budget=4, trace=tr,
+                          **kw).run(mk())
+        assert got == want
+        rows = {e.args["rows"] for e in tr.events() if e.name == "mixed_step"}
+        assert rows == ({2 * 4} if cfg is moe else {4 + 2})
+
+
 # ------------------------------------------------------------- unit pieces
 
 
@@ -328,6 +495,31 @@ def test_snapshot_ring_isolation_and_reuse():
     assert np.asarray(s).shape == (5,)
     with pytest.raises(ValueError):
         SnapshotRing(1)
+
+
+def test_allot_chunk_cap_serves_two_and_leaves_the_third_whole():
+    """``max_chunks=2`` with three cursors and budget to spare serves the
+    first two in allotment order and leaves the third untouched, in its
+    place for the next step; the default (no cap) serves all three."""
+    reqs = [Request(rid=i, prompt=np.arange(1, n + 1, dtype=np.int32),
+                    max_new=2) for i, n in enumerate((5, 4, 6))]
+
+    def curs():
+        return [PrefillCursor(r, r.prompt, slot=i, order=i)
+                for i, r in enumerate(reqs)]
+
+    cs = curs()
+    got = make_scheduler("fcfs").allot(cs, 32, max_chunks=2)
+    assert [(c.slot, n) for c, n in got] == [(0, 5), (1, 4)]
+    assert cs[2].remaining == 6 and cs[2].chunks == 0
+    # the held-back cursor goes first once the others are done
+    for c, n in got:
+        c.take(n)
+    later = make_scheduler("fcfs").allot([c for c in cs if not c.done], 32,
+                                         max_chunks=2)
+    assert [(c.slot, n) for c, n in later] == [(2, 6)]
+    assert [(c.slot, n) for c, n in make_scheduler("fcfs").allot(curs(), 32)] \
+        == [(0, 5), (1, 4), (2, 6)]
 
 
 def test_prefill_cursor_and_allot():

@@ -155,6 +155,18 @@ def test_paged_scatter(chip, as_chip, leaf, rows):
              ((SLOTS,), jnp.int32), ((SLOTS, N_BLOCKS), jnp.int32))
 
 
+@pytest.mark.parametrize("leaf", [((HKV, HEAD), jnp.int8), ((HKV,), jnp.float32)],
+                         ids=["kv8", "scales"])
+def test_paged_scatter_packed_rows(chip, as_chip, leaf):
+    """The packed mixed step's KV write: one token row per grid step for
+    its CHUNK + SLOTS rows, each through a one-entry block table."""
+    tail, dt = leaf
+    rows = CHUNK + SLOTS
+    _compile(chip, functools.partial(ops.paged_scatter, impl="pallas"),
+             ((N_PAGES, PAGE, *tail), dt), ((rows, 1, *tail), dt),
+             ((rows,), jnp.int32), ((rows, 1), jnp.int32))
+
+
 def test_flash_mha_prefill_block(chip, as_chip):
     fn = functools.partial(ops.flash_mha, causal=True, window=None, bq=512,
                            bk=512)

@@ -263,6 +263,25 @@ def test_engine_step_events_emitted(params):
     assert any(e.ph == "C" and e.name == "inflight" for e in tr.events())
 
 
+def test_mixed_step_records_rows_and_deferred_chunks(params):
+    """A packed mixed step records the rows it computed (budget + n_slots)
+    and how many cursors with work the chunk cap (two per step) held back:
+    four 3-token prompts admitted together fit one 16-token budget, so the
+    first mixed step serves two and defers two, the second serves those."""
+    tr = Tracer()
+    eng = ServeEngine(params, TINY, POLICY, n_slots=4, s_max=48, impl="jnp",
+                      cache="paged", page_size=8, n_pages=40, mixed=True,
+                      mixed_budget=16, trace=tr)
+    eng.run(_requests(lengths=(3, 3, 3, 3)))
+    steps = [e for e in tr.events() if e.name == "mixed_step"]
+    assert [e.args["rows"] for e in steps] == [16 + 4] * 2
+    assert [e.args["deferred_chunks"] for e in steps] == [2, 0]
+    assert [e.args["prefill_tokens"] for e in steps] == [6, 6]
+    assert [e.args["decode_lanes"] for e in steps] == [0, 2]
+    assert all("rows" not in e.args for e in tr.events()
+               if e.name == "decode_step")
+
+
 def test_prefill_chunk_spans(params):
     """A 3-chunk prompt produces sequential chunk spans inside the prefill
     span — serialized (emitted by ChunkedPrefill) and continuous (emitted
