@@ -2,14 +2,14 @@
 
 After the window has closed and the program's state is freed, a sample of
 the requests the engine finished, drawn from the seed and holding the
-longest of them, is run through the plain reference (``bench/reference.py``)
-once per request: the prompt followed by the served tokens. Every served
-token was a greedy choice, so at its position the reference's own best
-logit should lie above the served token's logit by no more than the
-rounding of the program's arithmetic can explain. The numbers compared are
-the widest such gap over every sampled token (in logits) and the mean gap;
-their limits, and the readings each was set from, are in
-``bench/limits/<cell>.json``.
+longest of them, is run through the plain reference of the configuration's
+family (``bench/families/<family>/reference.py``) once per request: the
+prompt followed by the served tokens. Every served token was a greedy
+choice, so at its position the reference's own best logit should lie above
+the served token's logit by no more than the rounding of the program's
+arithmetic can explain. The numbers compared are the widest such gap over
+every sampled token (in logits) and the mean gap; their limits, and the
+readings each was set from, are in ``bench/limits/<cell>.json``.
 
 A request that finished without all its tokens is wrong outright.
 """
@@ -18,21 +18,20 @@ from __future__ import annotations
 
 import numpy as np
 
-from bench import reference as R
-
-#: the keys of a configuration file the reference reads
-MODEL_KEYS = ("num_hidden_layers", "hidden_size", "num_attention_heads",
-              "num_key_value_heads", "head_dim", "intermediate_size",
-              "vocab_size", "sliding_window", "rope_theta", "rms_norm_eps")
-
-
-def model_view(c: dict) -> dict:
-    return {k: c.get(k) for k in MODEL_KEYS}
+from bench import families
 
 
 def precision_view(p: dict) -> dict:
     return {"act_bits": p["act_bits"], "act_clip": p["act_clip"],
             "kv_bits": p["kv_bits"], "weight_bits": dict(p["weight_bits"])}
+
+
+def reference_scores(seed: int, c: dict, prec: dict, seq, targets, **lower):
+    """The plain reference of ``c``'s family over ``seq`` under precision
+    ``prec``: at every position the largest logit, the logit of
+    ``targets`` and the argmax (``lower``: kv_bits / act_bits)."""
+    R = families.get(c).reference
+    return R.scores(seed, R.model_view(c), precision_view(prec), seq, targets, **lower)
 
 
 def sample(requests: dict, seed: int, min_tokens: int, max_requests: int) -> list:
@@ -61,7 +60,7 @@ def gaps(seed: int, c: dict, prec: dict, prompt, out, **lower) -> np.ndarray:
     targets = np.zeros(len(seq), np.int32)
     P = len(prompt)
     targets[P - 1:] = out
-    mx, at, _ = R.scores(seed, model_view(c), precision_view(prec), seq, targets, **lower)
+    mx, at, _ = reference_scores(seed, c, prec, seq, targets, **lower)
     return (mx - at)[P - 1:]
 
 

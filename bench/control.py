@@ -61,13 +61,12 @@ def control_gaps(seed: int, c: dict, r, lower: dict):
     import numpy as np
 
     from bench import check
-    from bench import reference as R
 
     seq = np.concatenate([np.asarray(r.prompt, np.int32), np.asarray(r.out[:-1], np.int32)])
     P = len(r.prompt)
-    m, p = check.model_view(c), check.precision_view(c["precision"])
-    _, _, top = R.scores(seed, m, p, seq, np.zeros(len(seq), np.int32), **lower)
-    mx, at, _ = R.scores(seed, m, p, seq, top)
+    p = c["precision"]
+    _, _, top = check.reference_scores(seed, c, p, seq, np.zeros(len(seq), np.int32), **lower)
+    mx, at, _ = check.reference_scores(seed, c, p, seq, top)
     return (mx - at)[P - 1:]
 
 

@@ -1,9 +1,9 @@
 """Plain reference of a dense GQA transformer under a stated precision.
 
 Straightforward ``jax.numpy``; it imports nothing of the program and reads
-nothing the program made. Weights are regenerated from the seed by
-``bench/weights.py``, layer by layer inside a scan, so the reference holds
-one layer's codes at a time.
+nothing the program made. Weights are regenerated from the seed by the
+family's ``weights.py``, layer by layer inside a scan, so the reference
+holds one layer's codes at a time.
 
 What the configuration states, and this file computes (the ``precision``
 block of ``bench/configs/<config>.json``):
@@ -32,6 +32,12 @@ import jax
 import jax.numpy as jnp
 
 from bench import weights as Wt
+from bench.families.dense_gqa import weights as FW
+
+#: the keys of a configuration file the reference reads
+MODEL_KEYS = ("num_hidden_layers", "hidden_size", "num_attention_heads",
+              "num_key_value_heads", "head_dim", "intermediate_size",
+              "vocab_size", "sliding_window", "rope_theta", "rms_norm_eps")
 
 Q_BLOCK = 512  # query rows per attention block
 HEAD_BLOCK = 512  # positions per LM-head block
@@ -107,8 +113,8 @@ def _layer(x, key, layer, c: dict, prec: dict, kv_bits: int, act_bits: int):
     H, Hkv, D = c["num_attention_heads"], c["num_key_value_heads"], c["head_dim"]
     wb = prec["weight_bits"]
     cl = prec["act_clip"]
-    w = Wt.layer_codes(key, c, layer, wb)
-    eps = {n: Wt.eps_w(s[1], wb[n]) for n, s in Wt.linear_shapes(c).items()}
+    w = FW.layer_codes(key, c, layer, wb)
+    eps = {n: Wt.eps_w(s[1], wb[n]) for n, s in FW.linear_shapes(c).items()}
     lin = lambda h, n: qlinear(h, w[n], eps[n], act_bits, cl)  # noqa: E731
     pos = jnp.arange(S)
     h = rms_norm(x, c["rms_norm_eps"])
@@ -126,7 +132,7 @@ def _layer(x, key, layer, c: dict, prec: dict, kv_bits: int, act_bits: int):
 @functools.partial(jax.jit, static_argnames=("c_items", "prec_items", "kv_bits", "act_bits"))
 def _hidden(key, tokens, *, c_items, prec_items, kv_bits, act_bits):
     c, prec = _undict(c_items), _undict(prec_items)
-    x = Wt.embed_table(key, c)[tokens]
+    x = FW.embed_table(key, c)[tokens]
 
     def body(x, layer):
         return _layer(x, key, layer, c, prec, kv_bits, act_bits), None
@@ -140,7 +146,7 @@ def _head(key, h, targets, *, c_items, prec_items, act_bits):
     """Per position: the largest logit, the logit of ``targets``, the argmax."""
     c, prec = _undict(c_items), _undict(prec_items)
     wb = prec["weight_bits"]["head"]
-    w = Wt.head_codes(key, c, wb)
+    w = FW.head_codes(key, c, wb)
     eps = Wt.eps_w(c["hidden_size"], wb)
     nb = h.shape[0] // HEAD_BLOCK
 
@@ -158,6 +164,10 @@ def _head(key, h, targets, *, c_items, prec_items, act_bits):
 
     mx, at, am = jax.lax.map(block, jnp.arange(nb))
     return mx.reshape(-1), at.reshape(-1), am.reshape(-1)
+
+
+def model_view(c: dict) -> dict:
+    return {k: c.get(k) for k in MODEL_KEYS}
 
 
 def _items(d: dict) -> tuple:

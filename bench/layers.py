@@ -6,6 +6,8 @@ returns a number or ``None`` where its run has nothing to read. ``ctx`` is a
 
   * ``cell``: the cell (configuration ``cell["config"]``, traffic mix
     ``cell["mix"]``);
+  * ``family``: the configuration's model family (``bench/families``),
+    whose ``counts`` give operations and bytes from its sizes;
   * ``rec``: the run's records (requests with their timestamps, every
     token's emit time, the load generator's lateness, the window
     ``t0``..``t1`` on the host clock);
@@ -21,7 +23,7 @@ from __future__ import annotations
 
 import bisect
 
-from bench import flops as F
+from bench import families
 from bench import peaks as PK
 
 STEP_SPANS = ("mixed_step", "decode_step")
@@ -31,6 +33,7 @@ class Context:
     def __init__(self, cell: dict, rec: dict, trace: dict, dev):
         self.cell, self.rec, self.trace = cell, rec, trace
         self.config, self.mix = cell["config"], cell["mix"]
+        self.family = families.get(self.config)
         self.spans = rec["spans"]
         self.peaks = PK.peaks(dev.device_kind)
         self.prof_t0, self.prof_t1 = rec["prof"].t_ready, rec["prof"].t_stop
@@ -83,7 +86,7 @@ class Context:
         """Model operations of the REAL tokens of one step, padding left
         out: every decode lane and prefill token through the layers,
         attention over its own context, the head where a token is sampled."""
-        c = self.config
+        c, F = self.config, self.family.counts
         total = sum(F.token_flops(c, n, head=True) for n in self.decode_contexts(e.ts))
         for off, n in self.prefill_chunks(e):
             total += n * F.linear_ops(c) + F.attn_ops_range(c, off + 1, off + n) + F.head_ops(c)

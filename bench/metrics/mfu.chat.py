@@ -1,8 +1,9 @@
 """Model FLOP/s utilization of the whole step, in %: the operations of the
 REAL tokens of the engine steps dispatched in the traced window (padding
-rows do not count; ``bench/flops.py``, from shapes and token counts) over
-the window times the chip's int8 peak (393 TOP/s on TPU v5e, ``bench/peaks.py``;
-the linears run int8 on the MXU). Moves ``ttft_p50_s``."""
+rows do not count; the family's ``counts.py``, from shapes and token
+counts) over the window times the chip's int8 peak (393 TOP/s on TPU v5e,
+``bench/peaks.py``; the linears run int8 on the MXU). Moves
+``ttft_p50_s``."""
 
 
 def read(ctx):

@@ -3,7 +3,8 @@
     python bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 A cell is an entry of ``workloads`` in ``BENCHMARK.json``: a configuration
-(``bench/configs/<config>.json``) under a traffic mix
+(``bench/configs/<config>.json``, whose ``harness`` key names its model
+family, ``bench/families/<family>/``) under a traffic mix
 (``bench/traffic/<mix>.json``). The run loads the program and makes its
 weights on the chip from the seed, warms up every shape the cell uses (all
 of that is ``setup_s``), plays the traffic for ``--seconds``, checks what
@@ -58,7 +59,10 @@ def read_json(path: str) -> dict:
 
 def load_cell(name: str, root: str = ROOT) -> dict:
     """The cell ``name`` with its configuration, traffic mix, limits and
-    the metrics it reports, all found by name."""
+    the metrics it reports, all found by name; its configuration's family
+    must be there."""
+    from bench import families
+
     bench = read_json(os.path.join(root, "BENCHMARK.json"))
     cell = next((w for w in bench["workloads"] if w["name"] == name), None)
     if cell is None:
@@ -67,14 +71,17 @@ def load_cell(name: str, root: str = ROOT) -> dict:
     mix = read_json(os.path.join(root, "bench", "traffic", cell["traffic"] + ".json"))
     if mix["loop"] != "open":
         die(f"traffic {cell['traffic']!r}: only open-loop mixes are played")
+    config = read_json(os.path.join(root, conf["file"]))
+    try:
+        families.get(config)
+    except LookupError as e:
+        die(str(e))
 
     def reports(m):
         return name in m.get("workloads", [name])
 
     return {
-        "name": name, "chips": cell["chips"],
-        "config": read_json(os.path.join(root, conf["file"])),
-        "mix": mix,
+        "name": name, "chips": cell["chips"], "config": config, "mix": mix,
         "limits": read_json(os.path.join(root, "bench", "limits", name + ".json")),
         "end_to_end": [m for m in bench["end_to_end"] if reports(m)],
         "per_layer": [m for m in bench["per_layer"] if reports(m)],
@@ -211,7 +218,7 @@ def serve(cell: dict, seed: int, seconds: float, trace: bool, *, fault=None) -> 
     import jax
     import numpy as np
 
-    from bench import loadgen
+    from bench import families, loadgen
     from bench import program as PG
 
     c, mix = cell["config"], cell["mix"]
@@ -220,7 +227,7 @@ def serve(cell: dict, seed: int, seconds: float, trace: bool, *, fault=None) -> 
     counter = CompileCounter()
     if fault is not None:
         fault.install()
-    acfg, pol = PG.arch(c), PG.policy(c)
+    acfg, pol = families.get(c).program.arch(c), PG.policy(c)
     phases = {"start": time.perf_counter() - T_PROC}  # interpreter, imports, chip
 
     def phase(name, t):

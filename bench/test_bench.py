@@ -5,23 +5,31 @@
 They skip the harness's look for a chip and drive the rest of a run: the
 traffic generator, the players, the check against the plain reference with
 faults planted in the timed path, the control, and the trace reduction on a
-small trace recorded on a TPU v5e chip (``bench/testdata``).
+small trace recorded on a TPU v5e chip (``bench/testdata``). Others hold the
+model families (``bench/families``) to their contract and the dense GQA
+family to numbers recorded before it moved there.
 """
 
 from __future__ import annotations
 
+import ast
+import glob
+import hashlib
 import json
+import math
 import os
 import subprocess
 import sys
 
+import numpy as np
 import pytest
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 sys.path.insert(0, ROOT)
 
-from bench import check, control, loadgen, run, trace_reduce  # noqa: E402
+from bench import check, control, families, loadgen, run, trace_reduce  # noqa: E402
+from bench import weights as Wt  # noqa: E402
 from bench.faults import Fault  # noqa: E402
 
 SEED = 2**31 + 4321  # past 32 signed bits: seeds may be that large
@@ -128,3 +136,123 @@ def test_benchmark_file_names_its_files_and_metrics_consistently():
             assert m["moves"] in reported[w], (m["name"], w)
     for w in cells:
         assert len(reported[w]) >= 2 and any(w in m["workloads"] for m in b["per_layer"])
+
+
+def golden() -> dict:
+    with open(os.path.join(HERE, "testdata", "dense_gqa_golden.json")) as f:
+        return json.load(f)
+
+
+def golden_config(g: dict) -> dict:
+    c = run.load_cell("internlm2.chat_open")["config"]
+    c.update(g["tiny"])
+    return c
+
+
+def leaf_hashes(tree) -> dict:
+    import jax
+
+    out = {}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
+        a = np.asarray(leaf)
+        out[jax.tree_util.keystr(path)] = hashlib.sha256(
+            f"{a.dtype}{a.shape}".encode() + a.tobytes()).hexdigest()
+    return out
+
+
+def test_the_dense_family_builds_the_recorded_parameter_leaves():
+    from bench import program as PG
+
+    g = golden()
+    c = golden_config(g)
+    params = PG.make_params(c, SEED, families.get(c).program.arch(c), PG.policy(c))
+    assert g["seed"] == SEED and leaf_hashes(params) == g["leaves"]
+
+
+def test_the_dense_family_reference_gives_the_recorded_scores():
+    g = golden()
+    c = golden_config(g)
+    mx, at, am = check.reference_scores(SEED, c, c["precision"], np.array(g["seq"], np.int32),
+                                        np.array(g["targets"], np.int32))
+    assert [float(x) for x in mx] == g["scores"]["max"]
+    assert [float(x) for x in at] == g["scores"]["at_target"]
+    assert [int(x) for x in am] == g["scores"]["argmax"]
+
+
+def test_the_dense_family_counts_give_the_recorded_numbers():
+    g = golden()
+    c = run.load_cell("internlm2.chat_open")["config"]
+    F = families.get(c).counts
+    got = {"linear_ops": F.linear_ops(c), "head_ops": F.head_ops(c),
+           "kv_bytes_per_token": F.kv_bytes_per_token(c)}
+    for n in g["contexts"]:
+        got[f"attn_ops@{n}"] = F.attn_ops(c, n)
+        got[f"attn_ops_range@1-{n}"] = F.attn_ops_range(c, 1, n)
+        got[f"attn_ops_range@{n // 2 + 1}-{n}"] = F.attn_ops_range(c, n // 2 + 1, n)
+        got[f"token_flops@{n}"] = F.token_flops(c, n, head=True)
+        got[f"token_flops_nohead@{n}"] = F.token_flops(c, n, head=False)
+        got[f"paged_attn_bytes@{n}"] = F.paged_attn_bytes(c, n, g["page_size"])
+    assert got == g["counts"]
+
+
+def imports_of(path: str) -> set:
+    """Every module an ``import`` statement of ``path`` names, relative
+    ones with their leading dots."""
+    with open(path) as f:
+        tree = ast.parse(f.read())
+    out = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            out |= {a.name for a in node.names}
+        elif isinstance(node, ast.ImportFrom):
+            mod = "." * node.level + (node.module or "")
+            out.add(mod)
+            out |= {(mod + "." if node.module else mod) + a.name for a in node.names}
+    return out
+
+
+def test_every_configuration_names_a_family_that_offers_the_contract():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        b = json.load(f)
+    for conf in b["configs"]:
+        with open(os.path.join(ROOT, conf["file"])) as f:
+            c = json.load(f)
+        fam = families.get(c)
+        for part, names in families.CONTRACT.items():
+            mod = getattr(fam, part)
+            assert [n for n in names if not hasattr(mod, n)] == [], (fam.name, part)
+        for part in ("weights", "reference", "counts"):
+            mods = imports_of(os.path.join(families.HERE, fam.name, part + ".py"))
+            bad = {m for m in mods if m.split(".")[0] == "repro" or m == "bench.program"
+                   or m.startswith("bench.program.") or m.lstrip(".") == "program"}
+            assert not bad, (fam.name, part, bad)
+    for c in ({"name": "x"}, {"name": "x", "harness": "no_such_family"}):
+        with pytest.raises(LookupError, match="dense_gqa"):
+            families.get(c)
+
+
+def test_no_module_outside_the_families_names_a_dense_only_key_or_leaf():
+    words = ("num_key_value_heads", "intermediate_size", "head_dim",
+             '"wq"', "'wq'", '"gate"', "'gate'")
+    found = {}
+    for path in glob.glob(os.path.join(HERE, "**", "*.py"), recursive=True):
+        rel = os.path.relpath(path, HERE)
+        if rel.startswith("families" + os.sep) or os.path.basename(rel).startswith("test_"):
+            continue
+        with open(path) as f:
+            text = f.read()
+        if any(w in text for w in words):
+            found[rel] = [w for w in words if w in text]
+    assert found == {}
+
+
+@pytest.mark.parametrize("bits,lo,hi,std", [(2, -1, 1, math.sqrt(0.5)),
+                                            (4, -6, 6, math.sqrt(5.0)),
+                                            (8, -126, 126, 36.95)])
+def test_weight_codes_lie_in_range_with_the_stated_std(bits, lo, hi, std):
+    assert abs(Wt.code_std(bits) - std) < 5e-3
+    q = np.asarray(Wt.codes(Wt.seed_key(SEED), (512, 1024), bits))
+    assert q.dtype == np.int8 and lo <= q.min() and q.max() <= hi
+    assert abs(q.std() - std) < 0.01 * std and abs(q.mean()) < 0.01 * std
+    if bits == 2:
+        assert set(np.unique(q)) == {-1, 0, 1}
